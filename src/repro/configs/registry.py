@@ -18,16 +18,20 @@ _MODULES = {
     "whisper-large-v3": ".whisper_large_v3",
     "qwen1.5-110b": ".qwen15_110b",
     "internvl3-14b": ".internvl3_14b_paper",
+    "internvl3-14b-1chip": ".internvl3_14b_paper:CONFIG_1CHIP",
 }
 
-ASSIGNED: List[str] = [k for k in _MODULES if k != "internvl3-14b"]
+ASSIGNED: List[str] = [
+    k for k in _MODULES if not k.startswith("internvl3-14b")
+]
 
 
 def get_config(name: str) -> ModelCfg:
     if name.endswith("-smoke"):
         return smoke_variant(get_config(name[: -len("-smoke")]))
-    mod = importlib.import_module(_MODULES[name], __package__)
-    return mod.CONFIG
+    module, _, attr = _MODULES[name].partition(":")
+    mod = importlib.import_module(module, __package__)
+    return getattr(mod, attr or "CONFIG")
 
 
 def all_configs() -> Dict[str, ModelCfg]:

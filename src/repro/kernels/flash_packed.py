@@ -180,8 +180,8 @@ def _packed_kernel(
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )                                                  # (Tq, Tk)
-        qs = qseg_ref[0, 0][:, None]                       # (Tq, 1)
-        ks = kseg_ref[0, 0][None, :]                       # (1, Tk)
+        qs = qseg_ref[0, 0]                                # (Tq, 1)
+        ks = kseg_ref[0, 0]                                # (1, Tk)
         mask = (qs == ks) & (qs >= 0)
         logits = jnp.where(mask, logits, NEG_INF)
 
@@ -244,8 +244,10 @@ def flash_packed_pallas(
     kt = k.transpose(0, 2, 1, 3)                       # (R, Hkv, L, D)
     vt = v.transpose(0, 2, 1, 3)
     seg = seg_id.astype(jnp.int32)
-    qseg = seg.reshape(R, n_q_tiles, tq)
-    kseg = seg.reshape(R, L // tk, tk)
+    # query segments as per-tile columns, key segments as per-tile rows:
+    # every block's two minor dims are then full (tq, 1) / (1, tk) tiles
+    qseg = seg.reshape(R, n_q_tiles, tq, 1)
+    kseg = seg.reshape(R, L // tk, 1, tk)
 
     kernel = functools.partial(_packed_kernel, t_max=t_max, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -253,17 +255,20 @@ def flash_packed_pallas(
         grid=(R, H, n_q_tiles, t_max),
         in_specs=[
             pl.BlockSpec((1, 1, tq, D), lambda r, h, iq, it, ids, cnt: (r, h, iq, 0)),
-            pl.BlockSpec((1, 1, tq), lambda r, h, iq, it, ids, cnt: (r, iq, 0)),
             pl.BlockSpec(
-                (1, 1, tk, D),
-                lambda r, h, iq, it, ids, cnt: (r, h // g, ids[r, iq, it], 0),
+                (1, 1, tq, 1), lambda r, h, iq, it, ids, cnt: (r, iq, 0, 0)
             ),
             pl.BlockSpec(
                 (1, 1, tk, D),
                 lambda r, h, iq, it, ids, cnt: (r, h // g, ids[r, iq, it], 0),
             ),
             pl.BlockSpec(
-                (1, 1, tk), lambda r, h, iq, it, ids, cnt: (r, ids[r, iq, it], 0)
+                (1, 1, tk, D),
+                lambda r, h, iq, it, ids, cnt: (r, h // g, ids[r, iq, it], 0),
+            ),
+            pl.BlockSpec(
+                (1, 1, 1, tk),
+                lambda r, h, iq, it, ids, cnt: (r, ids[r, iq, it], 0, 0),
             ),
         ],
         out_specs=pl.BlockSpec(

@@ -145,6 +145,25 @@ def build_block_map(
     )
 
 
+@functools.lru_cache(maxsize=256)
+def span_block_map(
+    start: int,
+    length: int,
+    kv_len: int,
+    *,
+    causal: bool = True,
+    window: int | None = None,
+) -> RefreshBlockMap:
+    """Visit list for the contiguous query positions ``[start, start +
+    length)``: a decode step, or a chunk written at a static cache
+    offset.  Cached, since serving decodes at the same layout-static
+    positions every window."""
+    return build_block_map(
+        np.arange(start, start + length, dtype=np.int32), kv_len,
+        causal=causal, window=window,
+    )
+
+
 def dense_block_map(
     q_pos,
     kv_len: int,
@@ -198,9 +217,9 @@ def _refresh_kernel(
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )                                                # (Tq, Tk)
-        qp = qpos_ref[0][:, None]                        # (Tq, 1)
+        qp = qpos_ref[0]                                 # (Tq, 1)
         kp = kid * tk + jax.lax.iota(jnp.int32, tk)[None, :]
-        mask = kvm_ref[0, 0][None, :] != 0               # (1, Tk) dynamic
+        mask = kvm_ref[0, 0] != 0                        # (1, Tk) dynamic
         if causal:
             mask &= kp <= qp
         if window is not None:
@@ -273,8 +292,10 @@ def flash_refresh_pallas(
     qt = q.transpose(0, 2, 1, 3)                      # (B, H, Sq, D)
     kt = k.transpose(0, 2, 1, 3)                      # (B, Hkv, Sk, D)
     vt = v.transpose(0, 2, 1, 3)
-    qp2 = q_pos.astype(jnp.int32).reshape(n_q_tiles, tq)
-    kvm = kv_valid.astype(jnp.int32).reshape(B, Sk // tk, tk)
+    # positions as per-tile columns and validity as per-tile rows: every
+    # block's two minor dims are then full (tq, 1) / (1, tk) tiles
+    qp2 = q_pos.astype(jnp.int32).reshape(n_q_tiles, tq, 1)
+    kvm = kv_valid.astype(jnp.int32).reshape(B, Sk // tk, 1, tk)
 
     kernel = functools.partial(
         _refresh_kernel, tk=tk, t_max=t_max, scale=scale,
@@ -285,7 +306,7 @@ def flash_refresh_pallas(
         grid=(B, H, n_q_tiles, t_max),
         in_specs=[
             pl.BlockSpec((1, 1, tq, D), lambda b, h, iq, it, ids, cnt: (b, h, iq, 0)),
-            pl.BlockSpec((1, tq), lambda b, h, iq, it, ids, cnt: (iq, 0)),
+            pl.BlockSpec((1, tq, 1), lambda b, h, iq, it, ids, cnt: (iq, 0, 0)),
             pl.BlockSpec(
                 (1, 1, tk, D),
                 lambda b, h, iq, it, ids, cnt: (b, h // g, ids[iq, it], 0),
@@ -295,7 +316,8 @@ def flash_refresh_pallas(
                 lambda b, h, iq, it, ids, cnt: (b, h // g, ids[iq, it], 0),
             ),
             pl.BlockSpec(
-                (1, 1, tk), lambda b, h, iq, it, ids, cnt: (b, ids[iq, it], 0)
+                (1, 1, 1, tk),
+                lambda b, h, iq, it, ids, cnt: (b, ids[iq, it], 0, 0),
             ),
         ],
         out_specs=pl.BlockSpec(
@@ -349,9 +371,9 @@ def _refresh_paged_kernel(
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        qp = qpos_ref[0][:, None]
+        qp = qpos_ref[0]
         kp = kid * tk + jax.lax.iota(jnp.int32, tk)[None, :]
-        mask = kvm_ref[0, 0][None, :] != 0
+        mask = kvm_ref[0, 0] != 0
         if causal:
             mask &= kp <= qp
         if window is not None:
@@ -420,9 +442,9 @@ def _refresh_paged_quant_kernel(
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        qp = qpos_ref[0][:, None]
+        qp = qpos_ref[0]
         kp = kid * tk + jax.lax.iota(jnp.int32, tk)[None, :]
-        mask = kvm_ref[0, 0][None, :] != 0
+        mask = kvm_ref[0, 0] != 0
         if causal:
             mask &= kp <= qp
         if window is not None:
@@ -511,8 +533,8 @@ def flash_refresh_paged_pallas(
     qt = q.transpose(0, 2, 1, 3)                      # (B, H, Sq, D)
     kt = k.transpose(1, 0, 2)                         # (Hkv, P_phys, D)
     vt = v.transpose(1, 0, 2)
-    qp2 = q_pos.astype(jnp.int32).reshape(n_q_tiles, tq)
-    kvm = kv_valid.astype(jnp.int32).reshape(B, n_pages, tk)
+    qp2 = q_pos.astype(jnp.int32).reshape(n_q_tiles, tq, 1)
+    kvm = kv_valid.astype(jnp.int32).reshape(B, n_pages, 1, tk)
 
     if cold is not None:
         k8, v8, k_scale, v_scale = cold
@@ -543,17 +565,17 @@ def flash_refresh_paged_pallas(
                     lambda b, h, iq, it, ids, cnt, pt, ks, vs: (b, h, iq, 0),
                 ),
                 pl.BlockSpec(
-                    (1, tq),
-                    lambda b, h, iq, it, ids, cnt, pt, ks, vs: (iq, 0),
+                    (1, tq, 1),
+                    lambda b, h, iq, it, ids, cnt, pt, ks, vs: (iq, 0, 0),
                 ),
                 pl.BlockSpec((1, tk, D), _hot_map),
                 pl.BlockSpec((1, tk, D), _cold_map),
                 pl.BlockSpec((1, tk, D), _hot_map),
                 pl.BlockSpec((1, tk, D), _cold_map),
                 pl.BlockSpec(
-                    (1, 1, tk),
+                    (1, 1, 1, tk),
                     lambda b, h, iq, it, ids, cnt, pt, ks, vs:
-                        (b, ids[iq, it], 0),
+                        (b, ids[iq, it], 0, 0),
                 ),
             ],
             out_specs=pl.BlockSpec(
@@ -588,7 +610,9 @@ def flash_refresh_paged_pallas(
             pl.BlockSpec(
                 (1, 1, tq, D), lambda b, h, iq, it, ids, cnt, pt: (b, h, iq, 0)
             ),
-            pl.BlockSpec((1, tq), lambda b, h, iq, it, ids, cnt, pt: (iq, 0)),
+            pl.BlockSpec(
+                (1, tq, 1), lambda b, h, iq, it, ids, cnt, pt: (iq, 0, 0)
+            ),
             # visit list -> page table -> physical kv tile
             pl.BlockSpec(
                 (1, tk, D),
@@ -600,8 +624,8 @@ def flash_refresh_paged_pallas(
             ),
             # validity stays logical (per stream, not per slab row)
             pl.BlockSpec(
-                (1, 1, tk),
-                lambda b, h, iq, it, ids, cnt, pt: (b, ids[iq, it], 0),
+                (1, 1, 1, tk),
+                lambda b, h, iq, it, ids, cnt, pt: (b, ids[iq, it], 0, 0),
             ),
         ],
         out_specs=pl.BlockSpec(

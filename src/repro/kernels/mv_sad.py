@@ -10,8 +10,13 @@ of shifted absolute-difference reductions.
 Layout notes (TPU):
   * the whole padded reference frame is mapped into VMEM once
     (448x448 f32 ~ 0.8 MB << 16 MB VMEM);
-  * per-candidate work is (block x W) elementwise + a reshape-reduction,
-    both lane-friendly since W is a multiple of the 16-px block.
+  * per-candidate work is (block x W) elementwise, a sublane sum over
+    the block rows, and a (1, W) x (W, W/block) matmul against a 0/1
+    block selector that sums each block's lanes — Mosaic cannot lay out
+    a reshape that splits the lane axis into (W/block, block);
+  * outputs are (H/block, 1, W/block) so each program's block is a full
+    (1, W/block) tile (a (1, W/block) block of a 2-D output would have a
+    second-minor dim that is neither a multiple of 8 nor the full dim).
 """
 from __future__ import annotations
 
@@ -28,22 +33,34 @@ def _mv_sad_kernel(
     wb = w // block
     n_cand = 2 * radius + 1
     cur = cur_ref[...]  # (block, W)
-    row0 = pl.program_id(0) * block  # this block-row's origin in the padded ref
+    # this block-row's (block+2r)-row band of the padded ref, loaded once
+    # from a sublane-aligned start; candidates slice it statically
+    row0 = pl.multiple_of(pl.program_id(0) * block, block)
+    band = prev_ref[pl.dslice(row0, block + 2 * radius), :]
+    # sel[x, j] = 1 iff pixel column x lies in macroblock column j
+    col = jax.lax.broadcasted_iota(jnp.int32, (w, wb), 0)
+    blk = jax.lax.broadcasted_iota(jnp.int32, (w, wb), 1)
+    sel = (col // block == blk).astype(jnp.float32)
 
-    best_sad = jnp.full((wb,), jnp.inf, jnp.float32)
-    best_idx = jnp.zeros((wb,), jnp.int32)
+    best_sad = jnp.full((1, wb), jnp.inf, jnp.float32)
+    best_idx = jnp.zeros((1, wb), jnp.int32)
     for idx in range(n_cand * n_cand):  # unrolled: static candidate count
         dy, dx = idx // n_cand, idx % n_cand
-        win = prev_ref[pl.dslice(row0 + dy, block), pl.dslice(dx, w)]
+        win = band[dy: dy + block, dx: dx + w]
         diff = jnp.abs(cur - win)
-        sads = diff.reshape(block, wb, block).sum(axis=(0, 2))  # (wb,)
+        colsum = diff.sum(axis=0, keepdims=True)              # (1, W)
+        sads = jax.lax.dot_general(
+            colsum, sel, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )                                                     # (1, wb)
         take = sads < best_sad
         best_sad = jnp.where(take, sads, best_sad)
         best_idx = jnp.where(take, idx, best_idx)
 
-    mvy_ref[0, :] = best_idx // n_cand - radius
-    mvx_ref[0, :] = best_idx % n_cand - radius
-    sad_ref[0, :] = best_sad
+    mvy_ref[0] = best_idx // n_cand - radius
+    mvx_ref[0] = best_idx % n_cand - radius
+    sad_ref[0] = best_sad
 
 
 @functools.partial(jax.jit, static_argnames=("block", "radius", "interpret"))
@@ -74,16 +91,16 @@ def mv_sad_pallas(
             pl.BlockSpec(prev_pad.shape, lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, wb), lambda i: (i, 0)),
-            pl.BlockSpec((1, wb), lambda i: (i, 0)),
-            pl.BlockSpec((1, wb), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, wb), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, wb), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, wb), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((hb, wb), jnp.int32),
-            jax.ShapeDtypeStruct((hb, wb), jnp.int32),
-            jax.ShapeDtypeStruct((hb, wb), jnp.float32),
+            jax.ShapeDtypeStruct((hb, 1, wb), jnp.int32),
+            jax.ShapeDtypeStruct((hb, 1, wb), jnp.int32),
+            jax.ShapeDtypeStruct((hb, 1, wb), jnp.float32),
         ],
         interpret=interpret,
     )(cur.astype(jnp.float32), prev_pad)
-    mv = jnp.stack([mvy, mvx], axis=-1)
-    return mv, sad
+    mv = jnp.stack([mvy[:, 0], mvx[:, 0]], axis=-1)
+    return mv, sad[:, 0]

@@ -10,6 +10,9 @@ tables in HBM), and the rotated tile is written back.
 
 Tiling: grid (B, S/Ts); block (1, Ts, n_kv, d_h).  d_h is 64–128 for all
 assigned archs -> the lane dim holds a full head; n_kv*Ts rows per tile.
+The deltas ride as a (B, S, 1) column so each program's (Ts, 1) block
+puts the tokens on sublanes, matching the key tile (a (1, Ts) block of a
+(B, S) array has a second-minor dim the TPU tiling refuses).
 """
 from __future__ import annotations
 
@@ -22,11 +25,13 @@ from jax.experimental import pallas as pl
 
 def _rope_shift_kernel(k_ref, delta_ref, out_ref, *, theta: float):
     k = k_ref[...].astype(jnp.float32)        # (1, Ts, Hk, D)
-    delta = delta_ref[...].astype(jnp.float32)  # (1, Ts)
+    delta = delta_ref[...].astype(jnp.float32)  # (1, Ts, 1)
     d_h = k.shape[-1]
     half = d_h // 2
-    freqs = 1.0 / (theta ** (jax.lax.iota(jnp.float32, half) / half))
-    ang = delta[..., None] * freqs            # (1, Ts, half)
+    # TPU iota is integer-only: build the exponent ramp in int32
+    ramp = jax.lax.iota(jnp.int32, half).astype(jnp.float32)
+    freqs = 1.0 / (theta ** (ramp / half))
+    ang = delta * freqs                       # (1, Ts, half)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
     k1, k2 = k[..., :half], k[..., half:]
@@ -58,9 +63,9 @@ def rope_shift_pallas(
         grid=(B, S // ts),
         in_specs=[
             pl.BlockSpec((1, ts, Hk, D), lambda b, i: (b, i, 0, 0)),
-            pl.BlockSpec((1, ts), lambda b, i: (b, i)),
+            pl.BlockSpec((1, ts, 1), lambda b, i: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, ts, Hk, D), lambda b, i: (b, i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(k.shape, k.dtype),
         interpret=interpret,
-    )(k, delta)
+    )(k, delta.reshape(B, S, 1))

@@ -2,25 +2,13 @@
 time, so importing this module does not touch jax device state)."""
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: every mesh axis is implicitly "auto"
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    """Version-compatible ``jax.make_mesh``: pass ``axis_types`` only on
-    jax versions that define it (all axes Auto either way)."""
-    if (AxisType is not None
-            and "axis_types" in inspect.signature(jax.make_mesh).parameters):
-        return jax.make_mesh(
-            shape, axes, axis_types=(AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto (sharding by the compiler)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -12,18 +12,25 @@ reports aggregate windows/s across sessions):
 
     PYTHONPATH=src python -m repro.launch.serve --streams 4 --videos 4
 
-By default the stage-pipelined async scheduler overlaps codec window
+Frames are square at the ViT's input resolution (``ViTCfg.image``).  By
+default the stage-pipelined async scheduler overlaps codec window
 slicing with accelerator work and keeps windows of different streams in
 different stages at once (docs/async_scheduler.md); ``--lockstep``
 forces the legacy one-group-per-step loop for A/B comparisons.  The
 summary reports per-stream p50/p99 window latency, TTFT, and per-stage
 occupancy alongside throughput.
+
+``serve()`` is the same run as a function returning the report dict
+(``chip_smoke.py`` drives it on the chip).
 """
 from __future__ import annotations
 
 import argparse
 import json
+import math
+import os
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -40,6 +47,23 @@ from ..serving import (
 )
 from ..training import checkpoint
 
+#: persistent compile cache used when JAX_COMPILATION_CACHE_DIR is unset:
+#: a fixed directory at the checkout root (the path is part of the key)
+CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its path.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and
+    left alone.  Otherwise the cache goes to ``CACHE_DIR``.  Called by
+    entry points only, never at import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    return str(CACHE_DIR)
+
 
 def default_vit(cfg) -> ViTCfg:
     return cfg.vit or ViTCfg(
@@ -48,14 +72,33 @@ def default_vit(cfg) -> ViTCfg:
     )
 
 
+def init_weights(cfg, v: ViTCfg, seed: int = 0):
+    """Random (LM params, ViT params) from ``seed``.
+
+    One jit of the keys: every leaf is drawn on the device straight into
+    its final stacked storage-dtype buffer, with no f32 or per-layer
+    copy held beside it (at full width those copies would not fit).
+    The keys are ``rbg``, which the backend's own bit generator draws:
+    the one-chip InternVL3-14B has 6.4e9 values to draw."""
+    def build(k_lm, k_vit):
+        params, _ = tfm.init_params(cfg, k_lm)
+        vparams, _ = split_tree(
+            vitm.init_vit(ParamBuilder(k_vit), v, cfg.d_model))
+        return params, vparams
+
+    return jax.jit(build)(jax.random.key(seed, impl="rbg"),
+                          jax.random.key(seed + 1, impl="rbg"))
+
+
 def build_pipeline(arch: str, mode: str, codec: CodecCfg,
                    ckpt: str | None = None, seed: int = 0,
-                   stale_dtype: str = "bf16") -> ServingPipeline:
+                   stale_dtype: str = "bf16",
+                   weights=None) -> ServingPipeline:
+    """``weights`` (from ``init_weights``) lets several pipelines share
+    one set of device weights."""
     cfg = get_config(arch)
     v = default_vit(cfg)
-    params, _ = tfm.init_params(cfg, jax.random.PRNGKey(seed))
-    pb = ParamBuilder(jax.random.PRNGKey(seed + 1))
-    vparams, _ = split_tree(vitm.init_vit(pb, v, cfg.d_model))
+    params, vparams = weights or init_weights(cfg, v, seed)
     if ckpt:
         params, _ = checkpoint.load(ckpt, params)
     return ServingPipeline(
@@ -70,18 +113,117 @@ def build_engine(arch: str, mode: str, codec: CodecCfg,
     return Engine.from_pipeline(build_pipeline(arch, mode, codec, ckpt, seed))
 
 
+def serve(
+    arch: str = "internvl3-14b-smoke",
+    mode: str = "codecflow",
+    *,
+    videos: int = 4,
+    frames: int = 32,
+    gop: int = 4,
+    window: int = 16,
+    stride: int = 4,
+    keep_ratio: float = 0.5,
+    streams: int = 1,
+    lockstep: bool = False,
+    ingest_workers: int = 2,
+    stale_dtype: str = "bf16",
+    ckpt: str | None = None,
+    seed: int = 0,
+    weights=None,
+) -> dict:
+    """Serve ``videos`` synthetic streams, ``streams`` at a time, and
+    return the run's report (see ``main`` for the flags)."""
+    codec = CodecCfg(
+        gop=gop, window_frames=window, stride_frames=stride,
+        keep_ratio=keep_ratio,
+    )
+    pipeline = build_pipeline(arch, mode, codec, ckpt, seed=seed,
+                              stale_dtype=stale_dtype, weights=weights)
+    hw = pipeline.v.image
+    clips = list(anomaly_dataset(videos, frames, hw, hw, seed=seed))
+
+    sched = Scheduler(pipeline, SchedulerCfg(
+        max_concurrent=max(1, streams),
+        pipelined=not lockstep,
+        ingest_workers=ingest_workers,
+    ))
+    t0 = time.time()
+    sids = [
+        sched.submit(StreamRequest(i, np.asarray(clip), tag=label))
+        for i, (clip, label) in enumerate(clips)
+    ]
+    n_throttled = 0
+    for ev in sched.events():
+        if isinstance(ev, StreamThrottled):
+            n_throttled += 1
+        elif isinstance(ev, WindowDone) and ev.window == 0:
+            print(f"# stream {ev.stream_id}: first answer "
+                  f"{ev.stats.answer}")
+    per_session = {sid: sched.session(sid).results for sid in sids}
+    wall = time.time() - t0
+
+    preds, truths = [], []
+    agg = dict(flops=0.0, t_vit=0.0, t_prefill=0.0, t_decode=0.0,
+               t_overhead=0.0, windows=0)
+    max_fallbacks, finite = 0, True
+    for sid in sids:
+        sess = sched.session(sid)
+        results = per_session[sid]
+        preds.append(video_prediction([r.stats.answer for r in results]))
+        truths.append(sess.request.tag)
+        for r in results:
+            s = r.stats
+            agg["flops"] += s.flops_vit + s.flops_prefill + s.flops_decode
+            agg["t_vit"] += s.t_vit
+            agg["t_prefill"] += s.t_prefill
+            agg["t_decode"] += s.t_decode
+            agg["t_overhead"] += s.t_overhead
+            agg["windows"] += 1
+            max_fallbacks = max(max_fallbacks, s.kernel_fallbacks)
+            finite &= all(math.isfinite(x) for x in s.logits_yes_no)
+    p, r, f1 = precision_recall_f1(preds, truths)
+    lat = sched.latency_quantiles()
+    ttft = sched.ttft_quantiles()
+    dev = jax.devices()[0]
+    return {
+        "arch": arch, "mode": mode, "streams": streams,
+        "scheduler": "lockstep" if lockstep else "pipelined",
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
+        "precision": p, "recall": r, "f1": f1,
+        "window_latency_p50_s": lat.get("p50", 0.0),
+        "window_latency_p99_s": lat.get("p99", 0.0),
+        "ttft_p50_s": ttft.get("p50", 0.0),
+        "ttft_p99_s": ttft.get("p99", 0.0),
+        "stage_occupancy": {k: round(v, 4)
+                            for k, v in sched.stage_occupancy().items()},
+        "streams_throttled": n_throttled,
+        "GFLOP_per_window": agg["flops"] / max(agg["windows"], 1) / 1e9,
+        "latency_per_window_s": (agg["t_vit"] + agg["t_prefill"]
+                                 + agg["t_decode"] + agg["t_overhead"])
+        / max(agg["windows"], 1),
+        "overhead_per_window_s": agg["t_overhead"] / max(agg["windows"], 1),
+        "kernel_fallbacks": sched.kernel_fallbacks,
+        "max_window_kernel_fallbacks": max_fallbacks,
+        "logits_finite": finite,
+        "windows_total": agg["windows"],
+        "windows_per_s": agg["windows"] / max(wall, 1e-9),
+        "wall_s": wall,
+    }
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internvl3-14b-smoke")
     ap.add_argument("--mode", default="codecflow")
     ap.add_argument("--videos", type=int, default=4)
     ap.add_argument("--frames", type=int, default=32)
-    ap.add_argument("--hw", type=int, default=112)
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--gop", type=int, default=4)
     ap.add_argument("--window", type=int, default=16)
     ap.add_argument("--stride", type=int, default=4)
     ap.add_argument("--keep-ratio", type=float, default=0.5)
+    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--streams", type=int, default=1,
                     help="concurrent sessions admitted by the scheduler; "
                          ">1 batches same-phase windows across streams")
@@ -97,75 +239,8 @@ def main() -> None:
                          "pages; int8 demotes them to the cold slab "
                          "(docs/paged_kv.md §Quantized cold pages)")
     args = ap.parse_args()
-
-    codec = CodecCfg(
-        gop=args.gop, window_frames=args.window, stride_frames=args.stride,
-        keep_ratio=args.keep_ratio,
-    )
-    pipeline = build_pipeline(args.arch, args.mode, codec, args.ckpt,
-                              stale_dtype=args.stale_dtype)
-    videos = list(anomaly_dataset(args.videos, args.frames, args.hw, args.hw))
-
-    sched = Scheduler(pipeline, SchedulerCfg(
-        max_concurrent=max(1, args.streams),
-        pipelined=not args.lockstep,
-        ingest_workers=args.ingest_workers,
-    ))
-    t0 = time.time()
-    sids = [
-        sched.submit(StreamRequest(i, np.asarray(frames), tag=label))
-        for i, (frames, label) in enumerate(videos)
-    ]
-    n_throttled = 0
-    for ev in sched.events():
-        if isinstance(ev, StreamThrottled):
-            n_throttled += 1
-        elif isinstance(ev, WindowDone) and ev.window == 0:
-            print(f"# stream {ev.stream_id}: first answer "
-                  f"{ev.stats.answer}")
-    per_session = {sid: sched.session(sid).results for sid in sids}
-    wall = time.time() - t0
-
-    preds, truths = [], []
-    agg = dict(flops=0.0, t_vit=0.0, t_prefill=0.0, t_decode=0.0,
-               t_overhead=0.0, windows=0)
-    for sid in sids:
-        sess = sched.session(sid)
-        results = per_session[sid]
-        preds.append(video_prediction([r.stats.answer for r in results]))
-        truths.append(sess.request.tag)
-        for r in results:
-            s = r.stats
-            agg["flops"] += s.flops_vit + s.flops_prefill + s.flops_decode
-            agg["t_vit"] += s.t_vit
-            agg["t_prefill"] += s.t_prefill
-            agg["t_decode"] += s.t_decode
-            agg["t_overhead"] += s.t_overhead
-            agg["windows"] += 1
-    p, r, f1 = precision_recall_f1(preds, truths)
-    lat = sched.latency_quantiles()
-    ttft = sched.ttft_quantiles()
-    out = {
-        "arch": args.arch, "mode": args.mode, "streams": args.streams,
-        "scheduler": "lockstep" if args.lockstep else "pipelined",
-        "precision": p, "recall": r, "f1": f1,
-        "window_latency_p50_s": lat.get("p50", 0.0),
-        "window_latency_p99_s": lat.get("p99", 0.0),
-        "ttft_p50_s": ttft.get("p50", 0.0),
-        "ttft_p99_s": ttft.get("p99", 0.0),
-        "stage_occupancy": {k: round(v, 4)
-                            for k, v in sched.stage_occupancy().items()},
-        "streams_throttled": n_throttled,
-        "GFLOP_per_window": agg["flops"] / max(agg["windows"], 1) / 1e9,
-        "latency_per_window_s": (agg["t_vit"] + agg["t_prefill"]
-                                 + agg["t_decode"] + agg["t_overhead"])
-        / max(agg["windows"], 1),
-        "overhead_per_window_s": agg["t_overhead"] / max(agg["windows"], 1),
-        "windows_total": agg["windows"],
-        "windows_per_s": agg["windows"] / max(wall, 1e-9),
-        "wall_s": wall,
-    }
-    print(json.dumps(out, indent=1))
+    enable_compile_cache()
+    print(json.dumps(serve(**vars(args)), indent=1))
 
 
 if __name__ == "__main__":

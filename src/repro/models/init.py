@@ -8,7 +8,7 @@ pjit in_shardings.
 """
 from __future__ import annotations
 
-from typing import Any, NamedTuple, Optional, Sequence, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,14 +43,15 @@ class ParamBuilder:
         return sub
 
     def dense(self, shape: Sequence[int], logical: Logical, scale: float | None = None) -> Param:
-        """Truncated-normal fan-in init."""
+        """Truncated-normal fan-in init, drawn in the storage dtype (an
+        f32 draw of a 151k-row embedding would hold 3.1 GB beside it)."""
         if self.abstract:
             return Param(jax.ShapeDtypeStruct(tuple(shape), self.dtype), tuple(logical))
         if scale is None:
             fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
             scale = fan_in ** -0.5
-        w = jax.random.truncated_normal(self._next(), -2, 2, tuple(shape), jnp.float32)
-        return Param((w * scale).astype(self.dtype), tuple(logical))
+        w = jax.random.truncated_normal(self._next(), -2, 2, tuple(shape), self.dtype)
+        return Param(w * jnp.asarray(scale, self.dtype), tuple(logical))
 
     def zeros(self, shape: Sequence[int], logical: Logical, dtype=None) -> Param:
         if self.abstract:
@@ -67,21 +68,27 @@ class ParamBuilder:
             return Param(jax.ShapeDtypeStruct(arr.shape, arr.dtype), tuple(logical))
         return Param(arr, tuple(logical))
 
+    def stacked(self, n: int, make: Callable[["ParamBuilder"], Any]) -> Any:
+        """``n`` layers of ``make(builder)`` stacked on a new leading
+        'layers' axis.  The layers are drawn one at a time under
+        ``lax.map``, straight into the stacked buffers: the program holds
+        one layer's ops whatever the depth, and no per-layer copy."""
+        tmpl = make(ParamBuilder(self._key, self.dtype, abstract=True))
+        if self.abstract:
+            arrays = jax.tree_util.tree_map(
+                lambda p: jax.ShapeDtypeStruct((n,) + p.array.shape, p.array.dtype),
+                tmpl, is_leaf=_is_param)
+        else:
+            arrays = jax.lax.map(
+                lambda k: split_tree(make(ParamBuilder(k, self.dtype)))[0],
+                jax.random.split(self._next(), n))
+        return jax.tree_util.tree_map(
+            lambda p, a: Param(a, (None,) + p.logical), tmpl, arrays,
+            is_leaf=_is_param)
+
 
 def split_tree(tree: Any) -> Tuple[Any, Any]:
     """Separate a pytree of Params into (params, specs)."""
     params = jax.tree_util.tree_map(lambda p: p.array, tree, is_leaf=_is_param)
     specs = jax.tree_util.tree_map(lambda p: p.logical, tree, is_leaf=_is_param)
     return params, specs
-
-
-def stack_layers(per_layer: Sequence[Any]) -> Any:
-    """Stack identical Param pytrees along a new leading 'layers' axis."""
-    def stack(*ps: Param) -> Param:
-        if isinstance(ps[0].array, jax.ShapeDtypeStruct):
-            a = ps[0].array
-            arr = jax.ShapeDtypeStruct((len(ps),) + tuple(a.shape), a.dtype)
-        else:
-            arr = jnp.stack([p.array for p in ps], 0)
-        return Param(arr, (None,) + ps[0].logical)
-    return jax.tree_util.tree_map(stack, *per_layer, is_leaf=_is_param)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelCfg, MoECfg
 from ..kernels import ops
+from ..kernels.flash_refresh import span_block_map
 from ..kernels.ref import apply_rope_ref
 from ..sharding.ctx import constrain
 from .init import ParamBuilder
@@ -235,10 +236,12 @@ def attention_block(
     in cache coordinates): the Pallas block-sparse kernel when a
     ``block_map`` for this geometry is supplied, the q-chunked oracle
     otherwise — no dense (B, S) score mask is materialized on the
-    kernel path.  ``block_map`` applies only to the scatter mode: its
-    ``q_pos`` must equal the scatter positions, which only that mode
-    guarantees (the contiguous mode's positions depend on the dynamic
-    ``cache_offset``).
+    kernel path.  A caller's ``block_map`` applies only to the scatter
+    mode: its ``q_pos`` must equal the scatter positions.  The
+    contiguous mode builds its own map when ``cache_offset`` is a
+    Python int (serving decodes at layout-static positions); a traced
+    offset leaves the positions unknown at trace time, so it takes the
+    oracle.
 
     Paged mode (``page_table`` (B, n_pages) int32): ``cache`` is the
     *batchless* per-layer slab of the shared KV pool (P_phys, n_kv, dh)
@@ -253,6 +256,14 @@ def attention_block(
     B, T, _ = x.shape
     q, k, v = _qkv(p, cfg, x, positions)
     window = cfg.sliding_window
+    if (cache is not None and scatter_idx is None
+            and isinstance(cache_offset, int)):
+        # a static offset (decode at a layout-static position) fixes the
+        # query positions, so the contiguous mode gets a visit list and
+        # runs the kernel; a traced offset cannot
+        kv_len = cache_len if cache_len is not None else cache.k.shape[1]
+        block_map = span_block_map(cache_offset, T, kv_len,
+                                   causal=causal, window=window)
 
     if cache is None:
         out = mha(q, k, v, positions, positions, valid, causal=causal,
@@ -313,7 +324,7 @@ def attention_block(
                 kval &= jax.lax.dynamic_update_slice_in_dim(
                     jnp.ones((B, S), bool), valid, cache_offset, 1
                 )
-            bm = None  # positions depend on the dynamic cache_offset
+            bm = block_map
         out = ops.flash_refresh_paged(
             q, ck, cv, positions, kval, page_table, page=page_size,
             causal=causal, window=window, block_map=bm, q_chunk=q_chunk,
@@ -345,7 +356,8 @@ def attention_block(
                 jnp.ones((B, ck.shape[1]), bool), valid, cache_offset, 1
             )[:, :S]
         out = ops.flash_refresh(q, kk, vv, positions, kval, causal=causal,
-                                window=window, q_chunk=q_chunk)
+                                window=window, block_map=block_map,
+                                q_chunk=q_chunk)
 
     out = out.reshape(B, T, cfg.n_heads * cfg.d_head) @ p["wo"]
     return out, new_cache

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelCfg
-from .init import ParamBuilder, split_tree, stack_layers
+from .init import ParamBuilder, split_tree
 from . import layers
 from .layers import KVCache, SSMCache
 
@@ -61,9 +61,6 @@ def init_params(cfg: ModelCfg, key: jax.Array, abstract: bool = False):
     """Returns (params, logical_specs) pytrees.
 
     ``abstract=True`` returns ShapeDtypeStructs (dry-run; no allocation).
-    In abstract mode, stacking one layer per pattern position suffices —
-    the repeat count only scales the leading axis — but we build the real
-    structure to keep the two paths identical.
     """
     pb = ParamBuilder(
         key, dtype=jnp.bfloat16 if cfg.dtype == "bfloat16" else F32,
@@ -75,23 +72,18 @@ def init_params(cfg: ModelCfg, key: jax.Array, abstract: bool = False):
     }
     if not cfg.tied_embeddings:
         tree["lm_head"] = pb.dense((cfg.d_model, cfg.vocab), ("embed", "vocab"))
-    blocks = []
-    for pos in range(cfg.period):
-        reps = [_init_block(pb, cfg, pos) for _ in range(cfg.repeats)]
-        blocks.append(stack_layers(reps))
-    tree["blocks"] = tuple(blocks)
+    tree["blocks"] = tuple(
+        pb.stacked(cfg.repeats, lambda b, pos=pos: _init_block(b, cfg, pos))
+        for pos in range(cfg.period)
+    )
     if cfg.enc_dec:
         enc_cfg = cfg  # same width; depth = enc_layers
-        enc = [
-            {
-                "ln1": layers.init_rmsnorm(pb, cfg.d_model),
-                "mixer": layers.init_attention(pb, enc_cfg),
-                "ln2": layers.init_rmsnorm(pb, cfg.d_model),
-                "ffn": layers.init_mlp(pb, cfg.d_model, cfg.d_ff),
-            }
-            for _ in range(cfg.enc_layers)
-        ]
-        tree["encoder"] = stack_layers(enc)
+        tree["encoder"] = pb.stacked(cfg.enc_layers, lambda b: {
+            "ln1": layers.init_rmsnorm(b, cfg.d_model),
+            "mixer": layers.init_attention(b, enc_cfg),
+            "ln2": layers.init_rmsnorm(b, cfg.d_model),
+            "ffn": layers.init_mlp(b, cfg.d_model, cfg.d_ff),
+        })
         tree["enc_norm"] = layers.init_rmsnorm(pb, cfg.d_model)
         tree["enc_embed"] = pb.dense((cfg.d_model, cfg.d_model), (None, "embed"))
     return split_tree(tree)
@@ -408,8 +400,9 @@ def decode_step(
     cfg: ModelCfg, params, token: jnp.ndarray, caches: Caches, cur_len,
     page_table=None, cache_len: Optional[int] = None, page_size: int = 128,
 ):
-    """One decode step.  token: (B, 1) int32; cur_len: scalar int32 (new
-    token's position / write index).  Returns (logits (B,V), caches).
+    """One decode step.  token: (B, 1) int32; cur_len: the new token's
+    position / write index, a Python int (static) or a scalar int32.
+    Returns (logits (B,V), caches).
 
     With ``page_table``, ``caches`` is the shared paged slab and
     ``cache_len`` must be passed explicitly (the slab's physical row
@@ -417,7 +410,9 @@ def decode_step(
     h = embed_tokens(cfg, params, token)
     B = h.shape[0]
     positions = jnp.broadcast_to(jnp.asarray(cur_len)[None, None], (B, 1)).astype(jnp.int32)
-    off = jnp.asarray(cur_len, jnp.int32)
+    # a Python-int position stays static: attention then runs the kernel
+    # with a visit list for it (layers.attention_block)
+    off = cur_len if isinstance(cur_len, int) else jnp.asarray(cur_len, jnp.int32)
     if cache_len is None:
         assert page_table is None, "paged decode needs an explicit cache_len"
         cache_len = caches_max_len(cfg, caches)

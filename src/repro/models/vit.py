@@ -30,26 +30,26 @@ import jax.numpy as jnp
 from ..configs.base import ViTCfg
 from ..kernels import ops
 from . import layers
-from .init import ParamBuilder, stack_layers
+from .init import ParamBuilder
 
 F32 = jnp.float32
 
 
 def init_vit(pb: ParamBuilder, v: ViTCfg, d_lm: int):
-    def block():
+    def block(b: ParamBuilder):
         return {
-            "ln1": layers.init_rmsnorm(pb, v.d_model),
-            "wq": pb.dense((v.d_model, v.d_model), ("embed", "heads")),
-            "wk": pb.dense((v.d_model, v.d_model), ("embed", "heads")),
-            "wv": pb.dense((v.d_model, v.d_model), ("embed", "heads")),
-            "wo": pb.dense((v.d_model, v.d_model), ("heads", "embed")),
-            "ln2": layers.init_rmsnorm(pb, v.d_model),
-            "ffn": layers.init_mlp(pb, v.d_model, v.d_ff),
+            "ln1": layers.init_rmsnorm(b, v.d_model),
+            "wq": b.dense((v.d_model, v.d_model), ("embed", "heads")),
+            "wk": b.dense((v.d_model, v.d_model), ("embed", "heads")),
+            "wv": b.dense((v.d_model, v.d_model), ("embed", "heads")),
+            "wo": b.dense((v.d_model, v.d_model), ("heads", "embed")),
+            "ln2": layers.init_rmsnorm(b, v.d_model),
+            "ffn": layers.init_mlp(b, v.d_model, v.d_ff),
         }
     return {
         "patch_embed": pb.dense((v.patch * v.patch, v.d_model), (None, "embed")),
         "pos_embed": pb.dense((v.n_patches, v.d_model), (None, "embed"), scale=0.02),
-        "blocks": stack_layers([block() for _ in range(v.n_layers)]),
+        "blocks": pb.stacked(v.n_layers, block),
         "final_norm": layers.init_rmsnorm(pb, v.d_model),
         "projector": pb.dense((v.group * v.group * v.d_model, d_lm), (None, "embed")),
     }

@@ -69,6 +69,12 @@ def _donate(*argnums: int) -> Tuple[int, ...]:
     is disabled there."""
     return argnums if jax.default_backend() != "cpu" else ()
 
+def _recurrent(cfg: ModelCfg) -> bool:
+    """SSM/hybrid stacks stream boundary state (``RecurrentPrefill``);
+    pure-attention stacks reuse KV (``AttentionPrefill``)."""
+    return cfg.family in ("ssm", "hybrid")
+
+
 # token conventions for the anomaly-detection workload
 PAD, BOS, YES, NO = 0, 1, 2, 3
 QUERY_IDS = (5, 6, 7, 8, 9, 10, 11, 12)   # "describe ... abuse? yes/no"
@@ -410,12 +416,6 @@ class AttentionPrefill:
         need = layout.total_len + ecfg.max_new_tokens
         self.cache_slots = -(-need // self.KV_TILE) * self.KV_TILE
         qc = ecfg.q_chunk
-        self._jit_prefill = jax.jit(
-            lambda params, tokens, caches, valid, embeds, off: tfm.prefill(
-                cfg, params, tokens, caches, valid=valid,
-                inputs_embeds=embeds, cache_offset=off, q_chunk=qc,
-            )
-        )
         self._jit_reuse = jax.jit(lambda caches: reuse_caches(cfg, caches, layout))
         # Static-refresh modes recompute exactly the layout's refresh
         # set every window, so the flash_refresh tile map is a per-layout
@@ -495,21 +495,18 @@ class AttentionPrefill:
             kv_pool.demote_pool_caches, static_argnums=3,
             donate_argnums=_donate(0),
         )
-        # fresh windows in paged mode go through scatter-mode run_stack
-        # (tfm.prefill assumes batched dense caches); their q positions
-        # are the full [0, total_len) range, so the visit list is a
-        # per-layout constant exactly like the refresh map.
-        self.fresh_map = (
-            build_block_map(
-                np.arange(layout.total_len, dtype=np.int32),
-                self.cache_slots, causal=True, window=cfg.sliding_window,
-            )
-            if self.paged else None
+        # fresh windows (paged slab or dense caches) go through
+        # scatter-mode run_stack; their q positions are the full
+        # [0, total_len) range, so the visit list is a per-layout
+        # constant exactly like the refresh map.
+        self.fresh_map = build_block_map(
+            np.arange(layout.total_len, dtype=np.int32),
+            self.cache_slots, causal=True, window=cfg.sliding_window,
         )
         fresh_map = self.fresh_map
         total = layout.total_len
 
-        def paged_fresh(params, caches, page_table, embeds, valid):
+        def fresh_prefill(params, caches, page_table, embeds, valid):
             S = embeds.shape[0]
             idx = jnp.arange(total, dtype=jnp.int32)
             positions = jnp.broadcast_to(idx[None], (S, total))
@@ -526,7 +523,8 @@ class AttentionPrefill:
             logits = tfm.lm_logits(cfg, params, hn[:, -1])
             return logits, new_caches
 
-        self._jit_paged_fresh = jax.jit(paged_fresh,
+        self._jit_fresh = jax.jit(fresh_prefill)
+        self._jit_paged_fresh = jax.jit(fresh_prefill,
                                         donate_argnums=_donate(1))
         self._jit_paged_reuse = jax.jit(
             lambda caches, pt: kv_pool.reuse_pool_caches(
@@ -662,9 +660,8 @@ class AttentionPrefill:
                                 lay.total_len, flops, 0.0,
                                 pages=pages, page_table=pt, age=age)
         caches = tfm.init_caches(self.cfg, S, alloc)
-        logits, caches, _ = self._jit_prefill(
-            self.params, jnp.zeros((S, lay.total_len), jnp.int32),
-            caches, valid, embeds, 0,
+        logits, caches = self._jit_fresh(
+            self.params, caches, None, embeds, valid
         )
         kv_valid = jnp.pad(valid, ((0, 0), (0, alloc - lay.total_len)))
         flops = flopcount.prefill_flops(self.cfg, lay.total_len, lay.total_len)
@@ -923,10 +920,16 @@ class GreedyDecoder:
         self.cfg = cfg
         self.params = params
         self.max_new_tokens = ecfg.max_new_tokens
+        # Attention stacks decode at layout-static positions (total_len
+        # + i every window), so the position is a static argument and
+        # the attention layers get a visit list for it: decode runs the
+        # refresh kernel.  Recurrent stacks' decode start grows every
+        # window, so there it stays a traced operand.
         self._jit_decode = jax.jit(
             lambda params, tok, caches, pos: tfm.decode_step(
                 cfg, params, tok, caches, pos
-            )
+            ),
+            static_argnums=() if _recurrent(cfg) else (3,),
         )
         # paged twin: caches are the shared slab, so the logical extent
         # cannot be read off the cache shape — it is a static closure of
@@ -936,7 +939,7 @@ class GreedyDecoder:
                 cfg, params, tok, caches, pos,
                 page_table=pt, cache_len=clen,
             ),
-            static_argnums=(5,),
+            static_argnums=(3, 5),
             donate_argnums=_donate(2),
         )
 
@@ -1041,7 +1044,7 @@ class ServingPipeline:
         self.prune = prune
         self.reuse = ecfg.mode in ("codecflow", "refresh_only", "cacheblend",
                                    "vlcache")
-        self.is_streaming_family = cfg.family in ("ssm", "hybrid")
+        self.is_streaming_family = _recurrent(cfg)
 
         self.frontend = CodecFrontend(c)
         self.encoder = VisualEncoder(vit_cfg, params_vit, c, self.layout,

@@ -350,7 +350,7 @@ def test_random_churn_with_demotion_preserves_accounting():
 @pytest.mark.parametrize("mode", ["codecflow", "cacheblend"])
 def test_int8_cold_pages_preserve_answers(stack, long_streams, mode):
     """Quantized vs all-bf16 serving through the Scheduler: window 0
-    (before any demotion) is bitwise identical, later windows stay
+    (before any demotion) agrees to f32 rounding, later windows stay
     within the int8 round-trip budget and never flip a yes/no answer,
     and both slabs (hot + cold + reservation) drain on close."""
     params, vparams, _ = stack
@@ -365,7 +365,15 @@ def test_int8_cold_pages_preserve_answers(stack, long_streams, mode):
         _quant_pipeline(params, vparams, mode, stale_dtype="bf16"),
         long_streams, max_concurrent=2)
     for sid in quant:
-        assert quant[sid][0] == bf16[sid][0]     # pre-demotion: bitwise
+        # Pre-demotion both slabs hold the same values, but not the same
+        # batches: the int8 pool admits streams staggered, so a stream's
+        # window 0 may run alone (batch 1) where the bf16 run fused it
+        # with another (batch 2).  XLA compiles a different program per
+        # batch shape, and float reassociation moves a logit by ~1 f32
+        # ulp; the bf16 slab serving that stream alone reproduces the
+        # int8 value bit for bit.  So the bound is f32 rounding.
+        np.testing.assert_allclose(quant[sid][0], bf16[sid][0],
+                                   rtol=1e-6, atol=1e-7)
         for lq, lb in zip(quant[sid], bf16[sid]):
             assert (lq[0] > lq[1]) == (lb[0] > lb[1]), (sid, lq, lb)
             assert max(abs(a - b) for a, b in zip(lq, lb)) < 0.5
