@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device,
+in % (one minus the union of operation intervals over the window)."""
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    return 100.0 * run.trace.idle_share
