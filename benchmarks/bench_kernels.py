@@ -337,9 +337,7 @@ def _serve_smoke(emit) -> dict:
             s.t_overhead for s in stats) / max(n_windows, 1)
         out[f"smoke_{mode}_kv_bytes_per_stream"] = max(
             (s.kv_bytes_per_stream for s in stats), default=0)
-        lat, ttft = sched.latency_quantiles(), sched.ttft_quantiles()
-        out[f"smoke_{mode}_latency_p50"] = lat.get("p50", 0.0)
-        out[f"smoke_{mode}_latency_p99"] = lat.get("p99", 0.0)
+        ttft = sched.ttft_quantiles()
         out[f"smoke_{mode}_ttft_p50"] = ttft.get("p50", 0.0)
         out[f"smoke_{mode}_ttft_p99"] = ttft.get("p99", 0.0)
         emit(csv_row(

@@ -161,10 +161,7 @@ def run_mode(mode: str, codec: CodecCfg = CODEC, videos=None,
         "windows": agg["windows"],
         "windows_per_s": agg["windows"] / max(wall, 1e-9),
         "scheduler": "pipelined" if pipelined else "lockstep",
-        # serving latency (enqueue->finalize async, group wall lockstep)
-        # and time-to-first-token, from the scheduler's own samples
-        "window_latency_p50": sched.latency_quantiles().get("p50", 0.0),
-        "window_latency_p99": sched.latency_quantiles().get("p99", 0.0),
+        # time-to-first-token, from the scheduler's own samples
         "ttft_p50": sched.ttft_quantiles().get("p50", 0.0),
         "ttft_p99": sched.ttft_quantiles().get("p99", 0.0),
         "stage_occupancy": sched.stage_occupancy(),

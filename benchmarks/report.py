@@ -115,10 +115,6 @@ def ci_summary(r) -> str:
          "{:.2f}"),
         ("fullcomp windows/s (smoke)", "smoke_fullcomp_windows_per_s",
          "{:.2f}"),
-        ("codecflow window latency p50 (smoke)",
-         "smoke_codecflow_latency_p50", "{:.3f} s"),
-        ("codecflow window latency p99 (smoke)",
-         "smoke_codecflow_latency_p99", "{:.3f} s"),
         ("codecflow TTFT p50 (smoke)", "smoke_codecflow_ttft_p50",
          "{:.3f} s"),
         ("codecflow TTFT p99 (smoke)", "smoke_codecflow_ttft_p99",
@@ -240,8 +236,6 @@ INFO_METRICS = (
     ("vitpack_0.25_wall_speedup_x", "up", "ViT pack wall speedup (keep 0.25)"),
     ("smoke_codecflow_windows_per_s", "up", "codecflow windows/s"),
     ("smoke_fullcomp_windows_per_s", "up", "fullcomp windows/s"),
-    ("smoke_codecflow_latency_p50", "down", "codecflow window latency p50"),
-    ("smoke_codecflow_latency_p99", "down", "codecflow window latency p99"),
     ("smoke_codecflow_ttft_p50", "down", "codecflow TTFT p50"),
     ("smoke_codecflow_ttft_p99", "down", "codecflow TTFT p99"),
     ("smoke_codecflow_t_overhead", "down", "codecflow t_overhead/window"),

@@ -67,9 +67,8 @@ def main() -> None:
     print(f"mode={args.mode} arch={args.arch}")
     print(f"streams={len(sids)} windows={n_windows} wall={wall:.1f}s "
           f"({n_windows / max(wall, 1e-9):.2f} windows/s aggregate)")
-    lat, ttft = sched.latency_quantiles(), sched.ttft_quantiles()
-    print(f"window latency p50={lat.get('p50', 0):.3f}s "
-          f"p99={lat.get('p99', 0):.3f}s  ttft p50={ttft.get('p50', 0):.3f}s")
+    ttft = sched.ttft_quantiles()
+    print(f"ttft p50={ttft.get('p50', 0):.3f}s p99={ttft.get('p99', 0):.3f}s")
     print(f"decisions={preds} truths={truths}  P={p:.2f} R={r:.2f} F1={f1:.2f}")
     print(f"total GFLOP={total_flops / 1e9:.2f}")
 

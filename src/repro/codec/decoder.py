@@ -57,9 +57,14 @@ class StreamDecoder:
         self.decode_count: np.ndarray | None = None
 
     def ingest(self, bitstream: Bitstream, meta: CodecMetadata) -> None:
-        self._frames = np.asarray(decode_stream(bitstream, self.cfg.block))
+        self.load(np.asarray(decode_stream(bitstream, self.cfg.block)), meta)
+
+    def load(self, frames: np.ndarray, meta: CodecMetadata) -> None:
+        """Buffer frames already decoded (``decode_stream``) and fetched
+        to the host, with their metadata."""
+        self._frames = frames
         self._meta = meta
-        self.decode_count = np.ones(self._frames.shape[0], np.int32)
+        self.decode_count = np.ones(frames.shape[0], np.int32)
 
     def window(self, k: int) -> Tuple[np.ndarray, CodecMetadata]:
         """k-th sliding window: frames [k*s, k*s + w)."""

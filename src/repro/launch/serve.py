@@ -17,8 +17,11 @@ default the stage-pipelined async scheduler overlaps codec window
 slicing with accelerator work and keeps windows of different streams in
 different stages at once (docs/async_scheduler.md); ``--lockstep``
 forces the legacy one-group-per-step loop for A/B comparisons.  The
-summary reports per-stream p50/p99 window latency, TTFT, and per-stage
-occupancy alongside throughput.
+summary reports TTFT and per-stage host occupancy alongside throughput.
+
+``--trace-dir DIR`` records the serve loop with the JAX profiler into
+``DIR``: the scheduler's ``serve.`` host spans beside the device's
+operations, one clock (docs/async_scheduler.md §Spans).
 
 ``serve()`` is the same run as a function returning the report dict
 (``chip_smoke.py`` drives it on the chip).
@@ -26,6 +29,7 @@ occupancy alongside throughput.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import math
 import os
@@ -130,6 +134,7 @@ def serve(
     ckpt: str | None = None,
     seed: int = 0,
     weights=None,
+    trace_dir: str | None = None,
 ) -> dict:
     """Serve ``videos`` synthetic streams, ``streams`` at a time, and
     return the run's report (see ``main`` for the flags)."""
@@ -147,18 +152,21 @@ def serve(
         pipelined=not lockstep,
         ingest_workers=ingest_workers,
     ))
+    traced = (jax.profiler.trace(trace_dir) if trace_dir
+              else contextlib.nullcontext())
     t0 = time.time()
-    sids = [
-        sched.submit(StreamRequest(i, np.asarray(clip), tag=label))
-        for i, (clip, label) in enumerate(clips)
-    ]
-    n_throttled = 0
-    for ev in sched.events():
-        if isinstance(ev, StreamThrottled):
-            n_throttled += 1
-        elif isinstance(ev, WindowDone) and ev.window == 0:
-            print(f"# stream {ev.stream_id}: first answer "
-                  f"{ev.stats.answer}")
+    with traced:
+        sids = [
+            sched.submit(StreamRequest(i, np.asarray(clip), tag=label))
+            for i, (clip, label) in enumerate(clips)
+        ]
+        n_throttled = 0
+        for ev in sched.events():
+            if isinstance(ev, StreamThrottled):
+                n_throttled += 1
+            elif isinstance(ev, WindowDone) and ev.window == 0:
+                print(f"# stream {ev.stream_id}: first answer "
+                      f"{ev.stats.answer}")
     per_session = {sid: sched.session(sid).results for sid in sids}
     wall = time.time() - t0
 
@@ -182,7 +190,6 @@ def serve(
             max_fallbacks = max(max_fallbacks, s.kernel_fallbacks)
             finite &= all(math.isfinite(x) for x in s.logits_yes_no)
     p, r, f1 = precision_recall_f1(preds, truths)
-    lat = sched.latency_quantiles()
     ttft = sched.ttft_quantiles()
     dev = jax.devices()[0]
     return {
@@ -191,15 +198,13 @@ def serve(
         "device": {"platform": dev.platform, "kind": dev.device_kind,
                    "count": len(jax.devices())},
         "precision": p, "recall": r, "f1": f1,
-        "window_latency_p50_s": lat.get("p50", 0.0),
-        "window_latency_p99_s": lat.get("p99", 0.0),
         "ttft_p50_s": ttft.get("p50", 0.0),
         "ttft_p99_s": ttft.get("p99", 0.0),
         "stage_occupancy": {k: round(v, 4)
                             for k, v in sched.stage_occupancy().items()},
         "streams_throttled": n_throttled,
         "GFLOP_per_window": agg["flops"] / max(agg["windows"], 1) / 1e9,
-        "latency_per_window_s": (agg["t_vit"] + agg["t_prefill"]
+        "host_s_per_window": (agg["t_vit"] + agg["t_prefill"]
                                  + agg["t_decode"] + agg["t_overhead"])
         / max(agg["windows"], 1),
         "overhead_per_window_s": agg["t_overhead"] / max(agg["windows"], 1),
@@ -238,6 +243,10 @@ def main() -> None:
                     help="storage dtype for stale (non-refreshed) KV "
                          "pages; int8 demotes them to the cold slab "
                          "(docs/paged_kv.md §Quantized cold pages)")
+    ap.add_argument("--trace-dir", default=None,
+                    help="record the serve loop with the JAX profiler "
+                         "into this directory (host spans and device "
+                         "operations on one clock)")
     args = ap.parse_args()
     enable_compile_cache()
     print(json.dumps(serve(**vars(args)), indent=1))

@@ -30,7 +30,7 @@ from the monolith (see module docstring history in engine.py).
 from __future__ import annotations
 
 import dataclasses
-import time
+import types
 from typing import (
     Any, Dict, List, NamedTuple, Optional, Protocol, Sequence, Tuple,
 )
@@ -40,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import CodecCfg, ModelCfg, ViTCfg
-from ..codec import StreamDecoder, encode_stream
+from ..codec import StreamDecoder, decode_stream, encode_stream
 from ..codec.metadata import CodecMetadata
 from ..core import (
     WindowLayout, capacity_groups, motion_mask, pack_plan,
@@ -52,6 +52,7 @@ from ..kernels.flash_refresh import build_block_map
 from ..models import layers
 from ..models import transformer as tfm
 from . import metrics
+from . import tracing
 from ..models import vit as vitm
 from . import flops as flopcount
 from .config import (                       # re-exported; grouped cfgs
@@ -68,6 +69,19 @@ def _donate(*argnums: int) -> Tuple[int, ...]:
     CPU does not implement donation (it would only warn), so donation
     is disabled there."""
     return argnums if jax.default_backend() != "cpu" else ()
+
+
+def _renamed(fn, name: str):
+    """``fn`` under another name: a jit of it compiles to the module
+    ``jit_<name>``, so each serving program keeps a name of its own in a
+    device trace (one body can back two programs, e.g. a dense and a
+    paged twin)."""
+    out = types.FunctionType(fn.__code__, fn.__globals__, name,
+                             fn.__defaults__, fn.__closure__)
+    out.__kwdefaults__ = fn.__kwdefaults__
+    out.__qualname__ = name
+    return out
+
 
 def _recurrent(cfg: ModelCfg) -> bool:
     """SSM/hybrid stacks stream boundary state (``RecurrentPrefill``);
@@ -103,6 +117,14 @@ class WindowStats:
     flops_vit: float             # buffer slots or padded capacity)
     flops_prefill: float
     flops_decode: float
+    # Host seconds of this window's share of each stage, read from the
+    # stage's ``serve.`` spans (``serving/tracing.py``): time spent
+    # dispatching, not device time (the device side is in a profiler
+    # trace).  t_codec: ``serve.codec.open`` over the stream's windows;
+    # t_vit: ``serve.vit.encode``; t_prefill: ``serve.prefill.dispatch``
+    # less the refresh selection; t_decode: ``serve.decode.dispatch``
+    # plus the answers' fetch; t_overhead: refresh selection and state
+    # (de)staging.
     t_codec: float
     t_vit: float
     t_prefill: float
@@ -148,7 +170,7 @@ class CodecStream:
     """Codec front-end state: the single-pass decode buffer + metadata."""
 
     decoder: StreamDecoder
-    t_ingest: float                  # encode + single-pass decode wall time
+    t_ingest: float                  # host seconds of ``serve.codec.open``
     n_windows: int
 
 
@@ -192,11 +214,15 @@ class CodecFrontend:
         self.codec = codec
 
     def open(self, frames: np.ndarray) -> CodecStream:
-        t0 = time.perf_counter()
-        bs, meta = encode_stream(jnp.asarray(frames, F32), self.codec)
-        dec = StreamDecoder(self.codec)
-        dec.ingest(bs, meta)
-        return CodecStream(dec, time.perf_counter() - t0, dec.n_windows())
+        with tracing.span("serve.codec.open", frames=len(frames)) as sp:
+            with tracing.span("serve.codec.encode"):
+                bs, meta = encode_stream(jnp.asarray(frames, F32), self.codec)
+            dec = StreamDecoder(self.codec)
+            with tracing.span("serve.codec.decode"):
+                recon = decode_stream(bs, self.codec.block)
+                with tracing.span("serve.codec.decode.fetch"):
+                    dec.load(np.asarray(recon), meta)
+        return CodecStream(dec, sp.seconds, dec.n_windows())
 
     def window_host(
         self, cs: CodecStream, k: int
@@ -247,10 +273,15 @@ class VisualEncoder:
         self.prune = prune
         self.packed = packed and prune
         self._range_cache: Dict[Tuple[int, int], tuple] = {}
-        self._jit_full = jax.jit(lambda vp, f: vitm.encode_full(vp, v, f))
-        self._jit_pruned = jax.jit(
-            lambda vp, f, pi, pv: vitm.encode_pruned_tokens(vp, v, f, pi, pv)
-        )
+
+        def vit_full(vp, f):
+            return vitm.encode_full(vp, v, f)
+
+        def vit_pruned(vp, f, pi, pv):
+            return vitm.encode_pruned_tokens(vp, v, f, pi, pv)
+
+        self._jit_full = jax.jit(vit_full)
+        self._jit_pruned = jax.jit(vit_pruned)
 
     def _split_range(self, frame_range: range) -> tuple:
         """(i_idx, p_idx, i_arr, p_arr) for a window frame range, cached
@@ -275,15 +306,22 @@ class VisualEncoder:
 
         Returns ((B, k_tokens, d_lm) tokens, packed slot count)."""
         v, kg = self.v, self.layout.k_tokens
-        plan = pack_plan(dec, v, tile=self.PACK_TILE)
+        with tracing.span("serve.vit.pack_plan") as sp:
+            with tracing.span("serve.vit.pack_plan.fetch"):
+                # check: allow-host-sync-under-jit(the host packs the kept groups: one fetch of the decision per encode group)
+                gv, pi = jax.device_get((dec.group_valid, dec.patch_idx))
+            plan = pack_plan(dec._replace(group_valid=gv, patch_idx=pi), v,
+                             tile=self.PACK_TILE)
+            sp.set(rows=plan.n_rows, slots=plan.n_slots)
         bm = plan.block_map
-        toks = vitm.encode_packed_tokens(
-            self.vparams, v, pframes,
-            jnp.asarray(plan.patch_src), jnp.asarray(plan.seg_id),
-            jnp.asarray(plan.group_src), jnp.asarray(plan.group_dst),
-            jnp.asarray(bm.tile_ids), jnp.asarray(bm.tile_count),
-            n_out=plan.n_frames * kg, tq=bm.tq, tk=bm.tk,
-        )
+        with tracing.span("serve.vit.packed"):
+            toks = vitm.encode_packed_tokens(
+                self.vparams, v, pframes,
+                jnp.asarray(plan.patch_src), jnp.asarray(plan.seg_id),
+                jnp.asarray(plan.group_src), jnp.asarray(plan.group_dst),
+                jnp.asarray(bm.tile_ids), jnp.asarray(bm.tile_count),
+                n_out=plan.n_frames * kg, tq=bm.tq, tk=bm.tk,
+            )
         return toks.reshape(plan.n_frames, kg, -1), plan.n_slots
 
     def encode(
@@ -308,10 +346,11 @@ class VisualEncoder:
         slots = np.zeros((S,), np.int64)
 
         if i_idx:
-            sel = frames[:, i_arr]                           # (S, Ni, H, Wd)
-            batch = sel.reshape((S * len(i_idx),) + sel.shape[2:])
-            toks = self._jit_full(self.vparams, batch)       # (S*Ni, G, d)
-            toks = toks.reshape((S, len(i_idx)) + toks.shape[1:])
+            with tracing.span("serve.vit.full", frames=S * len(i_idx)):
+                sel = frames[:, i_arr]                       # (S, Ni, H, Wd)
+                batch = sel.reshape((S * len(i_idx),) + sel.shape[2:])
+                toks = self._jit_full(self.vparams, batch)   # (S*Ni, G, d)
+                toks = toks.reshape((S, len(i_idx)) + toks.shape[1:])
             for j, f in enumerate(i_idx):
                 n_tok = lay.frame_tokens[f]
                 toks_by_frame[f] = toks[:, j, :n_tok]
@@ -320,36 +359,41 @@ class VisualEncoder:
             slots += len(i_idx) * v.n_patches
 
         if p_idx:
-            dyn, sco = [], []
-            for m in metas:
-                d, s = motion_mask(m, self.codec, v.patches_per_side)
-                dyn.append(d)
-                sco.append(s)
-            dyn = jnp.stack(dyn)                             # (S, W, pp, pp)
-            sco = jnp.stack(sco)
+            with tracing.span("serve.vit.motion_mask", windows=S):
+                dyn, sco = [], []
+                for m in metas:
+                    d, s = motion_mask(m, self.codec, v.patches_per_side)
+                    dyn.append(d)
+                    sco.append(s)
+                dyn = jnp.stack(dyn)                         # (S, W, pp, pp)
+                sco = jnp.stack(sco)
             Np = len(p_idx)
-            dsel = dyn[:, p_arr].reshape((S * Np,) + dyn.shape[2:])
-            ssel = sco[:, p_arr].reshape((S * Np,) + sco.shape[2:])
-            dec = select_tokens(dsel, ssel, v, lay.k_tokens)
+            with tracing.span("serve.vit.select", frames=S * Np):
+                dsel = dyn[:, p_arr].reshape((S * Np,) + dyn.shape[2:])
+                ssel = sco[:, p_arr].reshape((S * Np,) + sco.shape[2:])
+                dec = select_tokens(dsel, ssel, v, lay.k_tokens)
             pframes = frames[:, p_arr].reshape((S * Np,) + frames.shape[2:])
             if self.packed:
                 toks, n_slots = self._encode_packed(pframes, dec)
                 # shared buffer: attribute slots evenly across streams
                 slots += -(-n_slots // S)
             else:
-                toks_full = self._jit_pruned(
-                    self.vparams, pframes, dec.patch_idx, dec.patch_valid,
-                )                                            # (S*Np, G, d)
-                toks = jnp.take_along_axis(
-                    toks_full, dec.group_idx[..., None], 1
-                )
+                with tracing.span("serve.vit.pruned", frames=S * Np):
+                    toks_full = self._jit_pruned(
+                        self.vparams, pframes, dec.patch_idx,
+                        dec.patch_valid,
+                    )                                        # (S*Np, G, d)
+                    toks = jnp.take_along_axis(
+                        toks_full, dec.group_idx[..., None], 1
+                    )
                 slots += Np * dec.patch_idx.shape[1]
             toks = toks.reshape((S, Np) + toks.shape[1:])
             gval = dec.group_valid.reshape(S, Np, -1)
-            # check: allow-host-sync-under-jit(per-window stats fetch; one scalar per stream, after dispatch)
-            patches += np.asarray(
-                dec.patch_valid.reshape(S, -1).sum(axis=1), np.int64
-            )
+            with tracing.span("serve.vit.count.fetch"):
+                # check: allow-host-sync-under-jit(per-window stats fetch; one scalar per stream, after dispatch)
+                patches += np.asarray(
+                    dec.patch_valid.reshape(S, -1).sum(axis=1), np.int64
+                )
             for j, f in enumerate(p_idx):
                 n_tok = lay.frame_tokens[f]
                 toks_by_frame[f] = toks[:, j, :n_tok]
@@ -375,7 +419,7 @@ class PrefillResult(NamedTuple):
     tokens_valid: np.ndarray     # (S,) valid-token count per stream
     n_refreshed: int             # tokens recomputed through the LLM
     flops: float                 # prefill FLOPs per stream
-    t_select: float              # measured refresh-selection overhead
+    t_select: float              # host seconds of ``serve.prefill.select``
     page_table: Any = None       # (S, pages/stream) slab pages, paged mode
 
 
@@ -416,7 +460,11 @@ class AttentionPrefill:
         need = layout.total_len + ecfg.max_new_tokens
         self.cache_slots = -(-need // self.KV_TILE) * self.KV_TILE
         qc = ecfg.q_chunk
-        self._jit_reuse = jax.jit(lambda caches: reuse_caches(cfg, caches, layout))
+
+        def lm_reuse(caches):
+            return reuse_caches(cfg, caches, layout)
+
+        self._jit_reuse = jax.jit(lm_reuse)
         # Static-refresh modes recompute exactly the layout's refresh
         # set every window, so the flash_refresh tile map is a per-layout
         # constant (closed over by the jitted call below).  It covers
@@ -434,7 +482,8 @@ class AttentionPrefill:
         block_map = self.block_map
         alloc = self.cache_slots
 
-        def selective(params, caches, remb, rval, kvv, idx, page_table=None):
+        def lm_selective(params, caches, remb, rval, kvv, idx,
+                         page_table=None):
             B = remb.shape[0]
             positions = jnp.broadcast_to(idx[None], (B, idx.shape[0]))
             kv_full = kvv.at[:, idx].set(rval)
@@ -450,7 +499,7 @@ class AttentionPrefill:
             logits = tfm.lm_logits(cfg, params, hn[:, -1])
             return logits, new_caches, h
 
-        self._jit_selective = jax.jit(selective)
+        self._jit_selective = jax.jit(lm_selective)
         # paged twin donates the input slab: the selective pass threads
         # the shared KV slab functionally (slab in -> slab out), so on
         # TPU/GPU XLA updates the pages in place instead of copying the
@@ -458,7 +507,8 @@ class AttentionPrefill:
         # ``pool.slab`` to the output — the donated input is never read
         # again (docs/async_scheduler.md §Donation).
         self._jit_selective_paged = jax.jit(
-            selective, donate_argnums=_donate(1)
+            _renamed(lm_selective, "lm_selective_paged"),
+            donate_argnums=_donate(1),
         )
 
         # -- paged KV: shared slab + per-stream page tables ------------
@@ -492,8 +542,8 @@ class AttentionPrefill:
         )
         self.demote_after = max(1, ecfg.kv.demote_after)
         self._jit_demote = jax.jit(
-            kv_pool.demote_pool_caches, static_argnums=3,
-            donate_argnums=_donate(0),
+            _renamed(kv_pool.demote_pool_caches, "kv_demote"),
+            static_argnums=3, donate_argnums=_donate(0),
         )
         # fresh windows (paged slab or dense caches) go through
         # scatter-mode run_stack; their q positions are the full
@@ -506,7 +556,7 @@ class AttentionPrefill:
         fresh_map = self.fresh_map
         total = layout.total_len
 
-        def fresh_prefill(params, caches, page_table, embeds, valid):
+        def lm_fresh_prefill(params, caches, page_table, embeds, valid):
             S = embeds.shape[0]
             idx = jnp.arange(total, dtype=jnp.int32)
             positions = jnp.broadcast_to(idx[None], (S, total))
@@ -523,15 +573,17 @@ class AttentionPrefill:
             logits = tfm.lm_logits(cfg, params, hn[:, -1])
             return logits, new_caches
 
-        self._jit_fresh = jax.jit(fresh_prefill)
-        self._jit_paged_fresh = jax.jit(fresh_prefill,
-                                        donate_argnums=_donate(1))
-        self._jit_paged_reuse = jax.jit(
-            lambda caches, pt: kv_pool.reuse_pool_caches(
-                cfg, caches, pt, layout, self.KV_TILE
-            ),
-            donate_argnums=_donate(0),
+        def lm_reuse_paged(caches, pt):
+            return kv_pool.reuse_pool_caches(cfg, caches, pt, layout,
+                                             self.KV_TILE)
+
+        self._jit_fresh = jax.jit(lm_fresh_prefill)
+        self._jit_paged_fresh = jax.jit(
+            _renamed(lm_fresh_prefill, "lm_fresh_prefill_paged"),
+            donate_argnums=_donate(1),
         )
+        self._jit_paged_reuse = jax.jit(lm_reuse_paged,
+                                        donate_argnums=_donate(0))
 
     # -- paged pool lifecycle ------------------------------------------
     def ensure_pool(self, n_streams: int) -> None:
@@ -608,6 +660,9 @@ class AttentionPrefill:
                 n_refreshed, flops, t_select, pages=None,
                 page_table=None, age=None) -> PrefillResult:
         lay = self.layout
+        with tracing.span("serve.prefill.valid.fetch"):
+            # check: allow-host-sync-under-jit(WindowStats needs concrete counts; stage output already awaited)
+            tokens_valid = np.asarray(valid.sum(axis=1))
         if pages is not None:
             # paged: KV lives in the shared slab; the per-stream state
             # carries only page indices (host ints — staging them is the
@@ -626,10 +681,8 @@ class AttentionPrefill:
             decode_start=lay.total_len,
             flops_len=lambda i: lay.total_len + i + 1,
             state=state, tokens_vis=lay.vis_len,
-            # check: allow-host-sync-under-jit(WindowStats needs concrete counts; stage output already awaited)
-            tokens_valid=np.asarray(valid.sum(axis=1)),
-            n_refreshed=n_refreshed, flops=flops, t_select=t_select,
-            page_table=page_table,
+            tokens_valid=tokens_valid, n_refreshed=n_refreshed,
+            flops=flops, t_select=t_select, page_table=page_table,
         )
 
     # -- fresh window --------------------------------------------------
@@ -647,10 +700,12 @@ class AttentionPrefill:
             pages = pool.admit_streams(S, self.pages_per_stream,
                                        self.cold_per_stream)
             pt = jnp.asarray(pages, jnp.int32)
-            logits, slab = self._jit_paged_fresh(
-                self.params, pool.slab, pt, embeds, valid
-            )
-            pool.slab = slab
+            with tracing.span("serve.prefill.fresh", windows=S,
+                              refreshed=lay.total_len):
+                logits, slab = self._jit_paged_fresh(
+                    self.params, pool.slab, pt, embeds, valid
+                )
+                pool.slab = slab
             kv_valid = jnp.pad(valid, ((0, 0), (0, alloc - lay.total_len)))
             flops = flopcount.prefill_flops(
                 self.cfg, lay.total_len, lay.total_len
@@ -660,9 +715,11 @@ class AttentionPrefill:
                                 lay.total_len, flops, 0.0,
                                 pages=pages, page_table=pt, age=age)
         caches = tfm.init_caches(self.cfg, S, alloc)
-        logits, caches = self._jit_fresh(
-            self.params, caches, None, embeds, valid
-        )
+        with tracing.span("serve.prefill.fresh", windows=S,
+                          refreshed=lay.total_len):
+            logits, caches = self._jit_fresh(
+                self.params, caches, None, embeds, valid
+            )
         kv_valid = jnp.pad(valid, ((0, 0), (0, alloc - lay.total_len)))
         flops = flopcount.prefill_flops(self.cfg, lay.total_len, lay.total_len)
         return self._result(logits, vis, vval, caches, kv_valid, valid,
@@ -687,40 +744,45 @@ class AttentionPrefill:
         if self.paged:
             pages = state["pages"]
             pt = jnp.asarray(pages, jnp.int32)
-            caches = self._jit_paged_reuse(self.pool.slab, pt)
+            with tracing.span("serve.prefill.reuse", windows=S):
+                caches = self._jit_paged_reuse(self.pool.slab, pt)
             if self.quant:
                 # reuse first (it rewrote the overlap at full precision),
                 # THEN demote newly-eligible streams' overlap pages —
                 # the selective refresh below reads/writes through the
                 # updated mixed-precision page table.
                 age = state["age"] + 1
-                caches, pages, pt = self._demote(caches, pages, age)
+                with tracing.span("serve.prefill.demote", windows=S):
+                    caches, pages, pt = self._demote(caches, pages, age)
             self.pool.slab = caches
         else:
-            caches = self._jit_reuse(state["caches"])
+            with tracing.span("serve.prefill.reuse", windows=S):
+                caches = self._jit_reuse(state["caches"])
         prev_valid = state["kv_valid"]
         kvv = jnp.zeros((S, alloc), bool)
         kvv = kvv.at[:, : lay.overlap_tokens].set(
             prev_valid[:, lay.shift_tokens: lay.vis_len]
         )
-        t0 = time.perf_counter()
-        ridx = self.refresh_indices(embeds, caches, page_table=pt)
-        t_select = time.perf_counter() - t0
-        remb = jnp.take_along_axis(
-            embeds, jnp.asarray(ridx)[None, :, None], axis=1
-        )
-        rval = jnp.take_along_axis(valid, jnp.asarray(ridx)[None], axis=1)
-        jit_selective = (self._jit_selective_paged if self.paged
-                         else self._jit_selective)
-        logits, caches, _ = jit_selective(
-            self.params, caches, remb, rval, kvv, jnp.asarray(ridx), pt
-        )
-        if self.paged:
-            self.pool.slab = caches
+        with tracing.span("serve.prefill.select") as sel:
+            ridx = self.refresh_indices(embeds, caches, page_table=pt)
+        with tracing.span("serve.prefill.selective", windows=S,
+                          refreshed=len(ridx)):
+            remb = jnp.take_along_axis(
+                embeds, jnp.asarray(ridx)[None, :, None], axis=1
+            )
+            rval = jnp.take_along_axis(valid, jnp.asarray(ridx)[None],
+                                       axis=1)
+            jit_selective = (self._jit_selective_paged if self.paged
+                             else self._jit_selective)
+            logits, caches, _ = jit_selective(
+                self.params, caches, remb, rval, kvv, jnp.asarray(ridx), pt
+            )
+            if self.paged:
+                self.pool.slab = caches
         kv_valid = kvv.at[:, jnp.asarray(ridx)].set(rval)
         flops = flopcount.prefill_flops(self.cfg, len(ridx), lay.total_len)
         return self._result(logits, vis, vval, caches, kv_valid, valid,
-                            len(ridx), flops, t_select,
+                            len(ridx), flops, sel.seconds,
                             pages=pages, page_table=pt, age=age)
 
     def _demote(self, caches, pages: np.ndarray, age: np.ndarray):
@@ -816,8 +878,9 @@ class AttentionPrefill:
                 (k_new - k_reused.astype(k_new.dtype)).astype(F32),
                 axis=(-1, -2),
             )[0]
-            # check: allow-host-sync-under-jit(cacheblend selects its scatter set online: data-dependent indices must be concrete)
-            top = np.asarray(jnp.argsort(-dev)[:budget], np.int32)
+            with tracing.span("serve.prefill.select.fetch"):
+                # check: allow-host-sync-under-jit(cacheblend selects its scatter set online: data-dependent indices must be concrete)
+                top = np.asarray(jnp.argsort(-dev)[:budget], np.int32)
             return np.unique(np.concatenate([top, tail]))
         raise ValueError(mode)
 
@@ -837,12 +900,13 @@ class RecurrentPrefill:
         self.layout = layout
         self.ecfg = ecfg
         qc = ecfg.q_chunk
-        self._jit_prefill = jax.jit(
-            lambda params, tokens, caches, valid, embeds, off: tfm.prefill(
-                cfg, params, tokens, caches, valid=valid,
-                inputs_embeds=embeds, cache_offset=off, q_chunk=qc,
-            )
-        )
+
+        def lm_stream_prefill(params, tokens, caches, valid, embeds, off):
+            return tfm.prefill(cfg, params, tokens, caches, valid=valid,
+                               inputs_embeds=embeds, cache_offset=off,
+                               q_chunk=qc)
+
+        self._jit_prefill = jax.jit(lm_stream_prefill)
 
     batchable_step = True
 
@@ -873,15 +937,20 @@ class RecurrentPrefill:
             caches = state["caches"]
             offset = state["offset"]
         n_new = vis.shape[1]
-        _, caches, _ = self._jit_prefill(
-            self.params, jnp.zeros((S, n_new), jnp.int32), caches,
-            vval, vis, offset,
-        )
-        offset_vis = offset + n_new
-        q_logits, q_caches, _ = self._jit_prefill(
-            self.params, jnp.zeros((S, lay.query_len), jnp.int32), caches,
-            jnp.ones((S, lay.query_len), bool), qe, offset_vis,
-        )
+        with tracing.span("serve.prefill.append", windows=S,
+                          refreshed=n_new + lay.query_len):
+            _, caches, _ = self._jit_prefill(
+                self.params, jnp.zeros((S, n_new), jnp.int32), caches,
+                vval, vis, offset,
+            )
+            offset_vis = offset + n_new
+            q_logits, q_caches, _ = self._jit_prefill(
+                self.params, jnp.zeros((S, lay.query_len), jnp.int32),
+                caches, jnp.ones((S, lay.query_len), bool), qe, offset_vis,
+            )
+        with tracing.span("serve.prefill.valid.fetch"):
+            # check: allow-host-sync-under-jit(WindowStats needs concrete counts; stage output already awaited)
+            tokens_valid = np.asarray(vval.sum(axis=1))
         flops = flopcount.prefill_flops(
             self.cfg, n_new + lay.query_len, offset_vis + lay.query_len
         )
@@ -892,8 +961,7 @@ class RecurrentPrefill:
             state={"caches": caches, "offset": offset_vis,
                    "max_hist": max_hist},
             tokens_vis=n_new,
-            # check: allow-host-sync-under-jit(WindowStats needs concrete counts; stage output already awaited)
-            tokens_valid=np.asarray(vval.sum(axis=1)),
+            tokens_valid=tokens_valid,
             n_refreshed=n_new + lay.query_len, flops=flops, t_select=0.0,
         )
 
@@ -925,21 +993,21 @@ class GreedyDecoder:
         # the attention layers get a visit list for it: decode runs the
         # refresh kernel.  Recurrent stacks' decode start grows every
         # window, so there it stays a traced operand.
-        self._jit_decode = jax.jit(
-            lambda params, tok, caches, pos: tfm.decode_step(
-                cfg, params, tok, caches, pos
-            ),
-            static_argnums=() if _recurrent(cfg) else (3,),
-        )
+        def lm_decode(params, tok, caches, pos):
+            return tfm.decode_step(cfg, params, tok, caches, pos)
+
         # paged twin: caches are the shared slab, so the logical extent
         # cannot be read off the cache shape — it is a static closure of
         # the jit (cache_len) with the page table as a traced operand.
+        def lm_decode_paged(params, tok, caches, pos, pt, clen):
+            return tfm.decode_step(cfg, params, tok, caches, pos,
+                                   page_table=pt, cache_len=clen)
+
+        self._jit_decode = jax.jit(
+            lm_decode, static_argnums=() if _recurrent(cfg) else (3,),
+        )
         self._jit_decode_paged = jax.jit(
-            lambda params, tok, caches, pos, pt, clen: tfm.decode_step(
-                cfg, params, tok, caches, pos,
-                page_table=pt, cache_len=clen,
-            ),
-            static_argnums=(3, 5),
+            lm_decode_paged, static_argnums=(3, 5),
             donate_argnums=_donate(2),
         )
 
@@ -1000,7 +1068,7 @@ class EncodedWindows(NamedTuple):
     patches: np.ndarray          # (S,) decoded patch counts (host)
     slots: np.ndarray            # (S,) packed-slot counts (host)
     fresh: bool
-    t_vit: float
+    t_vit: float                 # host seconds of ``serve.vit.encode``
     fallbacks: int
 
 
@@ -1008,6 +1076,7 @@ class PrefilledWindows(NamedTuple):
     """Output of the prefill stage for one fused group of windows."""
 
     pr: PrefillResult
+    # host seconds of ``serve.prefill.dispatch`` less the refresh selection
     t_prefill: float
     fallbacks: int
 
@@ -1016,7 +1085,7 @@ class DecodedWindows(NamedTuple):
     """Output of the decode stage: answers dispatched, not yet synced."""
 
     pend: DecodePending
-    t_decode: float
+    t_decode: float              # host seconds of ``serve.decode.dispatch``
     fallbacks: int
 
 
@@ -1120,19 +1189,20 @@ class ServingPipeline:
         prefill/decode (lookahead)."""
         lay = self.layout
         disp0 = kernel_ops.dispatch_counts()
-        t0 = time.perf_counter()
         if fresh:
             rng = range(lay.window)
         else:
             rng = range(lay.window - lay.stride, lay.window)
-        vis, vval, patches, slots = self.encoder.encode(frames, metas, rng)
-        qe = self._query_embeds(frames.shape[0])
-        t_vit = time.perf_counter() - t0
+        with tracing.span("serve.vit.encode", windows=frames.shape[0],
+                          fresh=fresh) as sp:
+            vis, vval, patches, slots = self.encoder.encode(frames, metas,
+                                                            rng)
+            qe = self._query_embeds(frames.shape[0])
         fb = metrics.kernel_fallback_delta(
             disp0, kernel_ops.dispatch_counts()
         )
         return EncodedWindows(vis, vval, qe, patches, slots, fresh,
-                              t_vit, fb)
+                              sp.seconds, fb)
 
     def prefill_windows(
         self,
@@ -1144,16 +1214,16 @@ class ServingPipeline:
         (None for fresh groups).  Family differences live entirely
         behind the ``PrefillBackend`` protocol."""
         disp0 = kernel_ops.dispatch_counts()
-        t0 = time.perf_counter()
-        if enc.fresh:
-            pr = self.backend.fresh(enc.vis, enc.vval, enc.qe)
-        else:
-            pr = self.backend.step(enc.vis, enc.vval, enc.qe, state)
-        t_prefill = time.perf_counter() - t0 - pr.t_select
+        with tracing.span("serve.prefill.dispatch",
+                          windows=enc.vis.shape[0], fresh=enc.fresh) as sp:
+            if enc.fresh:
+                pr = self.backend.fresh(enc.vis, enc.vval, enc.qe)
+            else:
+                pr = self.backend.step(enc.vis, enc.vval, enc.qe, state)
         fb = metrics.kernel_fallback_delta(
             disp0, kernel_ops.dispatch_counts()
         )
-        return PrefilledWindows(pr, t_prefill, fb)
+        return PrefilledWindows(pr, sp.seconds - pr.t_select, fb)
 
     def decode_windows(self, pf: PrefilledWindows) -> DecodedWindows:
         """Stage 4: dispatch the greedy continuation and fold the decode
@@ -1161,18 +1231,19 @@ class ServingPipeline:
         stay on device until ``finalize_stats``."""
         pr = pf.pr
         disp0 = kernel_ops.dispatch_counts()
-        t0 = time.perf_counter()
-        pend = self.decoder.start(
-            pr.logits, pr.decode_caches, pr.decode_start, pr.flops_len,
-            page_table=pr.page_table,
-            cache_len=self.cache_slots if pr.page_table is not None else None,
-        )
-        self.backend.absorb_decode(pr.state, pend.caches)
-        t_decode = time.perf_counter() - t0
+        with tracing.span("serve.decode.dispatch",
+                          windows=pr.logits.shape[0]) as sp:
+            pend = self.decoder.start(
+                pr.logits, pr.decode_caches, pr.decode_start, pr.flops_len,
+                page_table=pr.page_table,
+                cache_len=(self.cache_slots if pr.page_table is not None
+                           else None),
+            )
+            self.backend.absorb_decode(pr.state, pend.caches)
         fb = metrics.kernel_fallback_delta(
             disp0, kernel_ops.dispatch_counts()
         )
-        return DecodedWindows(pend, t_decode, fb)
+        return DecodedWindows(pend, sp.seconds, fb)
 
     def finalize_stats(
         self,
@@ -1185,10 +1256,10 @@ class ServingPipeline:
         the decode share (it is the tail of the decode stream)."""
         pr, pend = pf.pr, dec.pend
         S = pend.answers.shape[0]
-        t0 = time.perf_counter()
-        yes_no = np.asarray(pend.yes_no, np.float64)
-        answers = np.asarray(pend.answers).astype(np.int64)
-        t_decode = dec.t_decode + (time.perf_counter() - t0)
+        with tracing.span("serve.finalize.fetch") as sp:
+            yes_no = np.asarray(pend.yes_no, np.float64)
+            answers = np.asarray(pend.answers).astype(np.int64)
+        t_decode = dec.t_decode + sp.seconds
         n_fallback = enc.fallbacks + pf.fallbacks + dec.fallbacks
         patches, slots = enc.patches, enc.slots
         kv_bytes = self.kv_bytes_per_stream()

@@ -55,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import flops as flopcount
+from . import tracing
 from .api import (
     EncodedWindows, ServingPipeline, StreamRequest, StreamSession,
     WindowResult, WindowStats,
@@ -164,7 +165,6 @@ class _EncRow(NamedTuple):
     t_vit: float                     # per-stream share of the fused call
     fallbacks: int                   # whole encode group's count (shared)
     t_codec: float                   # amortized codec time (stage 1)
-    t_enq: float                     # ingest-enqueue timestamp (latency)
 
 
 class _Inflight(NamedTuple):
@@ -261,7 +261,7 @@ class Scheduler:
         self._tick = 0
         # -- fleet metrics ---------------------------------------------
         self.windows_served = 0
-        self.t_serve = 0.0               # wall time inside step()/poll()
+        self.t_serve = 0.0               # host seconds of serve.step spans
         # fleet-level ViT packing efficiency: kept patches vs lanes the
         # encoder actually computed (padded capacity or packed buffer)
         self.vit_patches = 0
@@ -269,23 +269,24 @@ class Scheduler:
         # silent kernel→oracle fallbacks observed across all batched
         # stage calls (rows of one call share the count: add it once)
         self.kernel_fallbacks = 0
-        # busy seconds per stage (host-side dispatch + sync wall); with
-        # >1 ingest worker, ingest busy time can exceed scheduler wall
+        # host seconds per stage, read from the stages' ``serve.``
+        # spans (dispatch and fetch wall, not device time); with >1
+        # ingest worker, ingest busy time can exceed scheduler wall
         self.stage_busy: Dict[str, float] = {s: 0.0 for s in STAGES}
-        # per-stream serving latency: submit->first-answer (TTFT) and
-        # per-window enqueue->finalize
-        self.window_latencies: Dict[int, List[float]] = {}
+        # per-stream time to first answer: submit -> first window
+        # finalized (its answers fetched)
         self.ttft: Dict[int, float] = {}
 
     # -- session lifecycle ---------------------------------------------
     def submit(self, request: StreamRequest) -> int:
         """Open a session (codec ingest) and queue it for admission."""
-        stream = self.pipeline.frontend.open(request.frames)
-        sess = StreamSession(self._next_sid, request, stream)
-        self._next_sid += 1
-        self._sessions[sess.sid] = sess
-        self._queue.append(sess)
-        self._t_submit[sess.sid] = time.perf_counter()
+        with tracing.span("serve.submit", frames=len(request.frames)):
+            stream = self.pipeline.frontend.open(request.frames)
+            sess = StreamSession(self._next_sid, request, stream)
+            self._next_sid += 1
+            self._sessions[sess.sid] = sess
+            self._queue.append(sess)
+            self._t_submit[sess.sid] = time.perf_counter()
         return sess.sid
 
     def session(self, sid: int) -> StreamSession:
@@ -321,42 +322,43 @@ class Scheduler:
 
     # -- admission -----------------------------------------------------
     def _admit(self, events: Optional[List[SchedulerEvent]]) -> None:
-        for sid in [s for s, sess in self._active.items() if sess.done]:
-            del self._active[sid]
-            self._programs.pop(sid, None)
-        # paged backends: an admitted session claims its slab pages on
-        # its first fresh window — count sessions not yet holding pages
-        # and refuse admission the pool cannot back, instead of letting
-        # the fresh call hit PoolExhausted mid-batch
-        n_unbacked = sum(
-            1 for sess in self._active.values()
-            if not (sess.state and "pages" in sess.state)
-        )
-        while self._queue and len(self._active) < self.max_concurrent:
-            if not self.pipeline.can_admit(n_unbacked + 1):
-                head = self._queue[0]
-                if events is not None and head.sid not in self._throttled:
-                    self._throttled.add(head.sid)
-                    events.append(StreamThrottled(
-                        head.sid, head.request.stream_id
+        with tracing.span("serve.admit"):
+            for sid in [s for s, sess in self._active.items() if sess.done]:
+                del self._active[sid]
+                self._programs.pop(sid, None)
+            # paged backends: an admitted session claims its slab pages on
+            # its first fresh window — count sessions not yet holding pages
+            # and refuse admission the pool cannot back, instead of letting
+            # the fresh call hit PoolExhausted mid-batch
+            n_unbacked = sum(
+                1 for sess in self._active.values()
+                if not (sess.state and "pages" in sess.state)
+            )
+            while self._queue and len(self._active) < self.max_concurrent:
+                if not self.pipeline.can_admit(n_unbacked + 1):
+                    head = self._queue[0]
+                    if events is not None and head.sid not in self._throttled:
+                        self._throttled.add(head.sid)
+                        events.append(StreamThrottled(
+                            head.sid, head.request.stream_id
+                        ))
+                    break                    # wait for a stream to release
+                sess = self._queue.popleft()
+                self._throttled.discard(sess.sid)
+                if events is not None:
+                    events.append(StreamAdmitted(
+                        sess.sid, sess.request.stream_id
                     ))
-                break                    # wait for a stream to release
-            sess = self._queue.popleft()
-            self._throttled.discard(sess.sid)
-            if events is not None:
-                events.append(StreamAdmitted(
-                    sess.sid, sess.request.stream_id
-                ))
-            if not sess.done:            # zero-window streams finish here
-                self._active[sess.sid] = sess
-                self._programs[sess.sid] = _Program(
-                    sess, self._t_submit[sess.sid]
-                )
-                n_unbacked += 1
-            elif events is not None:
-                events.append(StreamDone(
-                    sess.sid, sess.request.stream_id, n_windows=0
-                ))
+                if not sess.done:            # zero-window streams finish here
+                    self._active[sess.sid] = sess
+                    self._programs[sess.sid] = _Program(
+                        sess, self._t_submit[sess.sid]
+                    )
+                    n_unbacked += 1
+                elif events is not None:
+                    events.append(StreamDone(
+                        sess.sid, sess.request.stream_id, n_windows=0
+                    ))
 
     # ==================================================================
     # event-driven API
@@ -366,29 +368,31 @@ class Scheduler:
         produced (possibly none when idle)."""
         events = self._event_buffer
         self._event_buffer = []
-        t0 = time.perf_counter()
-        self._admit(events)
-        if not self.cfg.pipelined:
-            self._serve_one_group(events)
-        else:
-            # dispatch order minimizes answer latency: windows whose
-            # encode landed last tick go to prefill+decode FIRST, then
-            # the next windows' encode (lookahead) queues behind them
-            # on the device, then the oldest inflight group is synced —
-            # by which time the device is already busy with this
-            # tick's dispatches and the ingest threads with the next
-            # windows' slicing.
-            did_prefill = self._prefill_pass()
-            did_encode = self._encode_pass()
-            if did_encode and not did_prefill:
-                did_prefill = self._prefill_pass()  # first-window catch-up
-            # groups dispatched this tick are only synced next tick —
-            # unless nothing was dispatched, in which case drain fully
-            # so the scheduler always makes progress toward idle
-            self._finalize_pass(events, drain=not (did_prefill
-                                                   or did_encode))
-            self._tick += 1
-        self.t_serve += time.perf_counter() - t0
+        with tracing.step("serve.step", step_num=self._tick) as sp:
+            self._admit(events)
+            if not self.cfg.pipelined:
+                self._serve_one_group(events)
+            else:
+                # dispatch order minimizes answer latency: windows whose
+                # encode landed last tick go to prefill+decode FIRST,
+                # then the next windows' encode (lookahead) queues
+                # behind them on the device, then the oldest inflight
+                # group is synced — by which time the device is already
+                # busy with this tick's dispatches and the ingest
+                # threads with the next windows' slicing.
+                did_prefill = self._prefill_pass()
+                did_encode = self._encode_pass()
+                if did_encode and not did_prefill:
+                    # first-window catch-up
+                    did_prefill = self._prefill_pass()
+                # groups dispatched this tick are only synced next tick
+                # — unless nothing was dispatched, in which case drain
+                # fully so the scheduler always makes progress toward
+                # idle
+                self._finalize_pass(events, drain=not (did_prefill
+                                                       or did_encode))
+                self._tick += 1
+        self.t_serve += sp.seconds
         return events
 
     def events(self) -> Iterator[SchedulerEvent]:
@@ -435,34 +439,35 @@ class Scheduler:
             "step()/events()/run() (docs/async_scheduler.md)",
             DeprecationWarning, stacklevel=2,
         )
-        t0 = time.perf_counter()
-        self._finalize_pass(self._event_buffer)  # flush async inflight
-        for prog in self._programs.values():
-            # drop stage-ahead work so a window dispatched by step() is
-            # never re-served by the lockstep path (don't mix the APIs)
-            prog.enc_rows.clear()
-            prog.futs.clear()
-            prog.next_ingest = prog.next_encode = prog.next_prefill = \
-                prog.sess.next_window
-        # events go to the deferred buffer, not to the caller (poll
-        # predates the event API and returns raw WindowResults) — but
-        # they MUST still be emitted, or a consumer that mixes poll()
-        # with events() sees WindowDone/StreamDone with no admission
-        # and the per-stream protocol breaks (tools/check
-        # event-protocol pass; EventProtocolValidator).  The buffer is
-        # delivered by the next step().
-        self._admit(self._event_buffer)
-        results = self._serve_one_group(self._event_buffer)
-        for prog in self._programs.values():
-            # re-sync stage cursors AFTER serving: programs created by
-            # this poll's admission start at window 0, and the lockstep
-            # serve advanced sess.next_window without moving the
-            # pipelined cursors — leaving them behind would make the
-            # next step() re-serve (and re-admit KV pages for) a window
-            # poll already delivered
-            prog.next_ingest = prog.next_encode = prog.next_prefill = \
-                prog.sess.next_window
-        self.t_serve += time.perf_counter() - t0
+        with tracing.step("serve.step", step_num=self._tick) as sp:
+            self._finalize_pass(self._event_buffer)  # flush async inflight
+            for prog in self._programs.values():
+                # drop stage-ahead work so a window dispatched by step()
+                # is never re-served by the lockstep path (don't mix the
+                # APIs)
+                prog.enc_rows.clear()
+                prog.futs.clear()
+                prog.next_ingest = prog.next_encode = prog.next_prefill = \
+                    prog.sess.next_window
+            # events go to the deferred buffer, not to the caller (poll
+            # predates the event API and returns raw WindowResults) —
+            # but they MUST still be emitted, or a consumer that mixes
+            # poll() with events() sees WindowDone/StreamDone with no
+            # admission and the per-stream protocol breaks (tools/check
+            # event-protocol pass; EventProtocolValidator).  The buffer
+            # is delivered by the next step().
+            self._admit(self._event_buffer)
+            results = self._serve_one_group(self._event_buffer)
+            for prog in self._programs.values():
+                # re-sync stage cursors AFTER serving: programs created
+                # by this poll's admission start at window 0, and the
+                # lockstep serve advanced sess.next_window without
+                # moving the pipelined cursors — leaving them behind
+                # would make the next step() re-serve (and re-admit KV
+                # pages for) a window poll already delivered
+                prog.next_ingest = prog.next_encode = prog.next_prefill = \
+                    prog.sess.next_window
+        self.t_serve += sp.seconds
         return results
 
     # ==================================================================
@@ -486,19 +491,19 @@ class Scheduler:
         if not groups:
             return []
         group = max(groups, key=len)[: self.max_batch]
-        t_poll0 = time.perf_counter()
 
         # stage 1: window slices (+ amortized codec time)
-        frames_l, metas, t_codecs = [], [], []
-        for sess in group:
-            wf, wm, tc = self.pipeline.frontend.window(
-                sess.stream, sess.next_window
-            )
-            frames_l.append(wf)
-            metas.append(wm)
-            t_codecs.append(tc)
-        frames = jnp.stack(frames_l, 0)
-        self._bump_stage("ingest", time.perf_counter() - t_poll0)
+        with tracing.span("serve.codec.slice", windows=len(group)) as sp:
+            frames_l, metas, t_codecs = [], [], []
+            for sess in group:
+                wf, wm, tc = self.pipeline.frontend.window(
+                    sess.stream, sess.next_window
+                )
+                frames_l.append(wf)
+                metas.append(wm)
+                t_codecs.append(tc)
+            frames = jnp.stack(frames_l, 0)
+        self._bump_stage("ingest", sp.seconds)
 
         # batched-state staging (measured scheduler overhead); singleton
         # groups bypass it — the batch=1 path stays copy-free like the
@@ -506,28 +511,27 @@ class Scheduler:
         fresh = group[0].state is None or not self.pipeline.reuse
         staged = [_staged_bytes(sess.state) for sess in group]
         tot_staged = sum(staged)
-        t0 = time.perf_counter()
-        if fresh:
-            state = None
-        elif len(group) == 1:
-            state = group[0].state
-        else:
-            state = _concat_states([s.state for s in group],
-                                   sids=[s.sid for s in group])
-        t_stage = time.perf_counter() - t0
+        with tracing.span("serve.prefill.state_concat") as concat:
+            if fresh:
+                state = None
+            elif len(group) == 1:
+                state = group[0].state
+            else:
+                state = _concat_states([s.state for s in group],
+                                       sids=[s.sid for s in group])
 
         stats, new_state = self.pipeline.serve_batch(frames, metas, state)
 
-        t0 = time.perf_counter()
-        if not self.pipeline.reuse:
-            # non-reuse modes never consume state: skip the split and
-            # don't pin dead cache pytrees on the sessions
-            per_states = [None] * len(group)
-        elif len(group) == 1:
-            per_states = [new_state]
-        else:
-            per_states = _split_state(new_state, len(group))
-        t_stage += time.perf_counter() - t0
+        with tracing.span("serve.prefill.state_split") as split:
+            if not self.pipeline.reuse:
+                # non-reuse modes never consume state: skip the split
+                # and don't pin dead cache pytrees on the sessions
+                per_states = [None] * len(group)
+            elif len(group) == 1:
+                per_states = [new_state]
+            else:
+                per_states = _split_state(new_state, len(group))
+        t_stage = concat.seconds + split.seconds
 
         results = []
         now = time.perf_counter()
@@ -560,9 +564,6 @@ class Scheduler:
             self._bump_stage("encode", st.t_vit)
             self._bump_stage("prefill", st.t_prefill)
             self._bump_stage("decode", st.t_decode)
-            self.window_latencies.setdefault(sess.sid, []).append(
-                now - t_poll0
-            )
             if window == 0:
                 self.ttft[sess.sid] = now - self._t_submit[sess.sid]
             if events is not None:
@@ -606,9 +607,9 @@ class Scheduler:
             self.stage_busy[stage] += dt
 
     def _ingest_one(self, sess: StreamSession, k: int):
-        t0 = time.perf_counter()
-        out = self.pipeline.frontend.window_host(sess.stream, k)
-        self._bump_stage("ingest", time.perf_counter() - t0)
+        with tracing.span("serve.codec.slice", window=k) as sp:
+            out = self.pipeline.frontend.window_host(sess.stream, k)
+        self._bump_stage("ingest", sp.seconds)
         return out
 
     def _ensure_ingest(self, prog: _Program) -> None:
@@ -621,55 +622,54 @@ class Scheduler:
         pool = self._ingest_pool()
         while prog.next_ingest < bound:
             k = prog.next_ingest
-            fut = (pool.submit(self._ingest_one, prog.sess, k)
-                   if pool is not None else None)
-            prog.futs[k] = (fut, time.perf_counter())
+            prog.futs[k] = (pool.submit(self._ingest_one, prog.sess, k)
+                            if pool is not None else None)
             prog.next_ingest += 1
 
     def _take_ingest(self, prog: _Program, k: int):
-        fut, t_enq = prog.futs.pop(k)
+        fut = prog.futs.pop(k)
         if fut is None:                      # inline (ingest_workers=0)
-            frames, meta, tc = self._ingest_one(prog.sess, k)
-        else:
-            frames, meta, tc = fut.result()
-        return frames, meta, tc, t_enq
+            return self._ingest_one(prog.sess, k)
+        with tracing.span("serve.encode.ingest_wait", window=k):
+            return fut.result()
 
     def _encode_pass(self) -> bool:
         """Fuse + dispatch ViT encode for every stream whose next
         window is sliced and within the lookahead bound."""
-        ready: Dict[bool, List[_Program]] = {}
-        for prog in self._programs.values():
-            self._ensure_ingest(prog)
-            w = prog.next_encode
-            if w >= prog.sess.stream.n_windows:
-                continue
-            if w > prog.next_prefill + self.cfg.lookahead:
-                continue
-            fresh = w == 0 or not self.pipeline.reuse
-            ready.setdefault(fresh, []).append(prog)
-        did = False
-        for fresh, progs in ready.items():
-            for chunk in _chunks(progs, self.max_batch):
-                self._encode_group(chunk, fresh)
-                did = True
+        with tracing.span("serve.encode"):
+            ready: Dict[bool, List[_Program]] = {}
+            for prog in self._programs.values():
+                self._ensure_ingest(prog)
+                w = prog.next_encode
+                if w >= prog.sess.stream.n_windows:
+                    continue
+                if w > prog.next_prefill + self.cfg.lookahead:
+                    continue
+                fresh = w == 0 or not self.pipeline.reuse
+                ready.setdefault(fresh, []).append(prog)
+            did = False
+            for fresh, progs in ready.items():
+                for chunk in _chunks(progs, self.max_batch):
+                    self._encode_group(chunk, fresh)
+                    did = True
         return did
 
     def _encode_group(self, progs: List[_Program], fresh: bool) -> None:
-        frames_l, metas, t_codecs, t_enqs = [], [], [], []
-        for prog in progs:
-            frames, meta, tc, t_enq = self._take_ingest(
-                prog, prog.next_encode
-            )
-            frames_l.append(frames)
-            metas.append(meta)
-            t_codecs.append(tc)
-            t_enqs.append(t_enq)
-        enc = self.pipeline.encode_windows(
-            jnp.asarray(np.stack(frames_l, 0)), metas, fresh
-        )
-        self._bump_stage("encode", enc.t_vit)
-        self.kernel_fallbacks += enc.fallbacks
         S = len(progs)
+        with tracing.span("serve.encode.group", windows=S,
+                          fresh=fresh) as sp:
+            frames_l, metas, t_codecs = [], [], []
+            for prog in progs:
+                frames, meta, tc = self._take_ingest(prog, prog.next_encode)
+                frames_l.append(frames)
+                metas.append(meta)
+                t_codecs.append(tc)
+            enc = self.pipeline.encode_windows(
+                jnp.asarray(np.stack(frames_l, 0)), metas, fresh
+            )
+            sp.set(kept=int(enc.patches.sum()), slots=int(enc.slots.sum()))
+        self._bump_stage("encode", sp.seconds)
+        self.kernel_fallbacks += enc.fallbacks
         for i, prog in enumerate(progs):
             w = prog.next_encode
             prog.enc_rows[w] = _EncRow(
@@ -677,7 +677,6 @@ class Scheduler:
                 patches=int(enc.patches[i]), slots=int(enc.slots[i]),
                 fresh=fresh, t_vit=enc.t_vit / S,
                 fallbacks=enc.fallbacks, t_codec=t_codecs[i],
-                t_enq=t_enqs[i],
             )
             prog.next_encode += 1
 
@@ -686,73 +685,76 @@ class Scheduler:
         next window is encoded (its state is ready by construction:
         window k-1's decode was dispatched before ``next_prefill``
         advanced to k)."""
-        groups: Dict[tuple, List[_Program]] = {}
-        for prog in self._programs.values():
-            row = prog.enc_rows.get(prog.next_prefill)
-            if row is None:
-                continue
-            key = (("fresh",) if row.fresh
-                   else self.pipeline.batch_key(prog.sess.state))
-            groups.setdefault(key, []).append(prog)
-        did = False
-        for key, progs in groups.items():
-            for chunk in _chunks(progs, self.max_batch):
-                self._dispatch_group(chunk)
-                did = True
+        with tracing.span("serve.prefill"):
+            groups: Dict[tuple, List[_Program]] = {}
+            for prog in self._programs.values():
+                row = prog.enc_rows.get(prog.next_prefill)
+                if row is None:
+                    continue
+                key = (("fresh",) if row.fresh
+                       else self.pipeline.batch_key(prog.sess.state))
+                groups.setdefault(key, []).append(prog)
+            did = False
+            for key, progs in groups.items():
+                for chunk in _chunks(progs, self.max_batch):
+                    self._dispatch_group(chunk)
+                    did = True
         return did
 
     def _dispatch_group(self, progs: List[_Program]) -> None:
         rows = [prog.enc_rows.pop(prog.next_prefill) for prog in progs]
         S = len(progs)
         fresh = rows[0].fresh
-        src = rows[0].enc
-        if (all(r.enc is src for r in rows)
-                and [r.idx for r in rows] == list(range(S))
-                and src.vis.shape[0] == S):
-            # prefill group == encode group (steady state): pass the
-            # fused arrays straight through, no re-staging
-            enc_g = src
-        else:
-            enc_g = EncodedWindows(
-                vis=jnp.concatenate(
-                    [r.enc.vis[r.idx: r.idx + 1] for r in rows], 0),
-                vval=jnp.concatenate(
-                    [r.enc.vval[r.idx: r.idx + 1] for r in rows], 0),
-                qe=jnp.concatenate(
-                    [r.enc.qe[r.idx: r.idx + 1] for r in rows], 0),
-                patches=np.array([r.patches for r in rows]),
-                slots=np.array([r.slots for r in rows]),
-                fresh=fresh, t_vit=0.0, fallbacks=0,
-            )
-        staged = [_staged_bytes(p.sess.state) for p in progs]
-        tot_staged = sum(staged)
-        t0 = time.perf_counter()
-        if fresh:
-            state = None
-        elif S == 1:
-            state = progs[0].sess.state
-        else:
-            state = _concat_states([p.sess.state for p in progs],
-                                   sids=[p.sess.sid for p in progs])
-        t_stage = time.perf_counter() - t0
+        with tracing.span("serve.prefill.group", windows=S,
+                          fresh=fresh) as group:
+            src = rows[0].enc
+            if (all(r.enc is src for r in rows)
+                    and [r.idx for r in rows] == list(range(S))
+                    and src.vis.shape[0] == S):
+                # prefill group == encode group (steady state): pass the
+                # fused arrays straight through, no re-staging
+                enc_g = src
+            else:
+                enc_g = EncodedWindows(
+                    vis=jnp.concatenate(
+                        [r.enc.vis[r.idx: r.idx + 1] for r in rows], 0),
+                    vval=jnp.concatenate(
+                        [r.enc.vval[r.idx: r.idx + 1] for r in rows], 0),
+                    qe=jnp.concatenate(
+                        [r.enc.qe[r.idx: r.idx + 1] for r in rows], 0),
+                    patches=np.array([r.patches for r in rows]),
+                    slots=np.array([r.slots for r in rows]),
+                    fresh=fresh, t_vit=0.0, fallbacks=0,
+                )
+            staged = [_staged_bytes(p.sess.state) for p in progs]
+            tot_staged = sum(staged)
+            with tracing.span("serve.prefill.state_concat") as concat:
+                if fresh:
+                    state = None
+                elif S == 1:
+                    state = progs[0].sess.state
+                else:
+                    state = _concat_states([p.sess.state for p in progs],
+                                           sids=[p.sess.sid for p in progs])
 
-        pf = self.pipeline.prefill_windows(enc_g, state)
-        dec = self.pipeline.decode_windows(pf)
+            pf = self.pipeline.prefill_windows(enc_g, state)
+            dec = self.pipeline.decode_windows(pf)
 
-        t0 = time.perf_counter()
-        if not self.pipeline.reuse:
-            per_states = [None] * S
-        elif S == 1:
-            per_states = [pf.pr.state]
-        else:
-            per_states = _split_state(pf.pr.state, S)
-        t_stage += time.perf_counter() - t0
+            with tracing.span("serve.prefill.state_split") as split:
+                if not self.pipeline.reuse:
+                    per_states = [None] * S
+                elif S == 1:
+                    per_states = [pf.pr.state]
+                else:
+                    per_states = _split_state(pf.pr.state, S)
+            group.set(refreshed=pf.pr.n_refreshed)
+        t_stage = concat.seconds + split.seconds
         # the new state is live as soon as it is dispatched — window
         # k+1's prefill chains on it through device data dependencies,
         # no host sync needed (done streams release at finalize)
         for prog, st in zip(progs, per_states):
             prog.sess.state = st
-        self._bump_stage("prefill", pf.t_prefill + t_stage)
+        self._bump_stage("prefill", group.seconds - dec.t_decode)
         self._bump_stage("decode", dec.t_decode)
         self.kernel_fallbacks += pf.fallbacks + dec.fallbacks
         shares = [b / tot_staged if tot_staged else 1 / S for b in staged]
@@ -770,67 +772,71 @@ class Scheduler:
         synced — the groups dispatched this tick stay queued on the
         device, so the host blocks on window k only after window k+1's
         prefill/decode is already lined up behind it."""
-        while self._inflight and (drain
-                                  or self._inflight[0].tick < self._tick):
-            self._finalize_group(self._inflight.popleft(), events)
+        with tracing.span("serve.finalize"):
+            while self._inflight and (
+                    drain or self._inflight[0].tick < self._tick):
+                self._finalize_group(self._inflight.popleft(), events)
 
     def _finalize_group(self, g: _Inflight,
                         events: List[SchedulerEvent]) -> None:
         """Sync one fused group's answers off device and emit its
         ``WindowDone`` (and possibly ``StreamDone``) events."""
         pend = g.dec.pend
-        t0 = time.perf_counter()
-        yes_no = np.asarray(pend.yes_no, np.float64)
-        answers = np.asarray(pend.answers).astype(np.int64)
-        t_sync = time.perf_counter() - t0
-        self._bump_stage("finalize", t_sync)
-        now = time.perf_counter()
         pr = g.pf.pr
         S = len(g.progs)
-        t_decode = g.dec.t_decode + t_sync   # sync is the decode tail
-        kv_bytes = self.pipeline.kv_bytes_per_stream()
-        for i, (prog, row) in enumerate(zip(g.progs, g.rows)):
-            sess = prog.sess
-            st = WindowStats(
-                answer=int(answers[i]),
-                logits_yes_no=(float(yes_no[i, 0]), float(yes_no[i, 1])),
-                tokens_vis=pr.tokens_vis,
-                tokens_valid=int(pr.tokens_valid[i]),
-                tokens_refreshed=pr.n_refreshed,
-                vit_patches=row.patches,
-                vit_slots=row.slots,
-                flops_vit=flopcount.vit_flops(self.pipeline.v, row.patches),
-                flops_prefill=pr.flops,
-                flops_decode=pend.flops_decode,
-                t_codec=row.t_codec,
-                t_vit=row.t_vit,
-                t_prefill=g.pf.t_prefill / S,
-                t_decode=t_decode / S,
-                t_overhead=pr.t_select / S + g.t_stage * g.shares[i],
-                kernel_fallbacks=(row.fallbacks + g.pf.fallbacks
-                                  + g.dec.fallbacks),
-                kv_bytes_per_stream=kv_bytes,
-            )
-            res = WindowResult(sess.request.stream_id, sess.sid,
-                               row.window, st)
-            sess.results.append(res)
-            sess.next_window += 1
-            self.windows_served += 1
-            self.vit_patches += st.vit_patches
-            self.vit_slots += st.vit_slots
-            self.window_latencies.setdefault(sess.sid, []).append(
-                now - row.t_enq
-            )
-            if row.window == 0:
-                self.ttft[sess.sid] = now - prog.t_submit
-            events.append(WindowDone(sess.sid, sess.request.stream_id, res))
-            if sess.done:
-                self.pipeline.release_state(sess.state)
-                sess.state = None
-                events.append(StreamDone(
-                    sess.sid, sess.request.stream_id,
-                    n_windows=sess.next_window,
-                ))
+        with tracing.span("serve.finalize.group", windows=S) as group:
+            with tracing.span("serve.finalize.fetch") as fetch:
+                yes_no = np.asarray(pend.yes_no, np.float64)
+                answers = np.asarray(pend.answers).astype(np.int64)
+            now = time.perf_counter()
+            # the fetch is the decode tail
+            t_decode = g.dec.t_decode + fetch.seconds
+            kv_bytes = self.pipeline.kv_bytes_per_stream()
+            with tracing.span("serve.finalize.stats"):
+                for i, (prog, row) in enumerate(zip(g.progs, g.rows)):
+                    sess = prog.sess
+                    st = WindowStats(
+                        answer=int(answers[i]),
+                        logits_yes_no=(float(yes_no[i, 0]),
+                                       float(yes_no[i, 1])),
+                        tokens_vis=pr.tokens_vis,
+                        tokens_valid=int(pr.tokens_valid[i]),
+                        tokens_refreshed=pr.n_refreshed,
+                        vit_patches=row.patches,
+                        vit_slots=row.slots,
+                        flops_vit=flopcount.vit_flops(self.pipeline.v,
+                                                      row.patches),
+                        flops_prefill=pr.flops,
+                        flops_decode=pend.flops_decode,
+                        t_codec=row.t_codec,
+                        t_vit=row.t_vit,
+                        t_prefill=g.pf.t_prefill / S,
+                        t_decode=t_decode / S,
+                        t_overhead=(pr.t_select / S
+                                    + g.t_stage * g.shares[i]),
+                        kernel_fallbacks=(row.fallbacks + g.pf.fallbacks
+                                          + g.dec.fallbacks),
+                        kv_bytes_per_stream=kv_bytes,
+                    )
+                    res = WindowResult(sess.request.stream_id, sess.sid,
+                                       row.window, st)
+                    sess.results.append(res)
+                    sess.next_window += 1
+                    self.windows_served += 1
+                    self.vit_patches += st.vit_patches
+                    self.vit_slots += st.vit_slots
+                    if row.window == 0:
+                        self.ttft[sess.sid] = now - prog.t_submit
+                    events.append(WindowDone(sess.sid,
+                                             sess.request.stream_id, res))
+                    if sess.done:
+                        self.pipeline.release_state(sess.state)
+                        sess.state = None
+                        events.append(StreamDone(
+                            sess.sid, sess.request.stream_id,
+                            n_windows=sess.next_window,
+                        ))
+        self._bump_stage("finalize", group.seconds)
 
     # ==================================================================
     # fleet metrics
@@ -853,18 +859,6 @@ class Scheduler:
         utilization is pinned at keep-fraction x capacity)."""
         return self.vit_patches / max(self.vit_slots, 1)
 
-    def latency_quantiles(self) -> Dict[str, float]:
-        """p50/p99/mean of per-window serving latency (enqueue→finalize
-        in pipelined mode, group-serve wall in lockstep), seconds."""
-        flat = [v for ls in self.window_latencies.values() for v in ls]
-        if not flat:
-            return {}
-        return {
-            "p50": float(np.percentile(flat, 50)),
-            "p99": float(np.percentile(flat, 99)),
-            "mean": float(np.mean(flat)),
-        }
-
     def ttft_quantiles(self) -> Dict[str, float]:
         """p50/p99/mean of per-stream time-to-first-token (submit →
         first window finalized), seconds."""
@@ -878,9 +872,11 @@ class Scheduler:
         }
 
     def stage_occupancy(self) -> Dict[str, float]:
-        """Per-stage busy seconds per scheduler wall second.  Ingest can
-        exceed 1.0 with multiple worker threads; a lockstep run sums to
-        ~1.0 across stages (no overlap by construction)."""
+        """Per-stage host seconds per second inside ``step()``, both read
+        from the ``serve.`` spans: host dispatch and fetch time, not
+        device time (a profiler trace has that).  Ingest can exceed 1.0
+        with multiple worker threads; a lockstep run sums to ~1.0 across
+        stages (no overlap by construction)."""
         wall = max(self.t_serve, 1e-9)
         with self._metrics_lock:
             busy = dict(self.stage_busy)

@@ -221,7 +221,7 @@ def test_scheduler_inventory_on_real_tree():
     assert findings == []
     by_attr = {r.attr: r for r in rows if r.cls == "Scheduler"}
     assert by_attr["stage_busy"].label == "lock-guarded"
-    for attr in ("kernel_fallbacks", "window_latencies", "ttft",
+    for attr in ("kernel_fallbacks", "ttft",
                  "windows_served", "vit_patches", "vit_slots"):
         assert by_attr[attr].label == "main-thread-only", attr
     assert by_attr["pipeline"].label == "immutable-after-init"
