@@ -28,6 +28,8 @@ from typing import Any, Callable, Mapping, Optional, Tuple
 
 import jax.numpy as jnp
 
+from .flash_refresh import paged_group_fits_vmem
+
 # Dtypes the Pallas kernels (and their oracles) accept for tensor
 # operands.  f32 is the accumulator dtype everywhere; bf16/f16 are the
 # storage dtypes the serving path feeds.
@@ -711,6 +713,14 @@ FLASH_REFRESH_PAGED = KernelContract(
             "map-window",
             "map and call agree on the sliding window",
             lambda f: f["map_window"] == f["window"],
+        ),
+        Rule(
+            "group-vmem",
+            "one grouped step (the g query heads of a kv head against a "
+            "page) fits the scoped VMEM; the int8 twin steps per head",
+            lambda f: f["has_cold"] or paged_group_fits_vmem(
+                f["q_shape"][2] // f["k_shape"][1], f["q_shape"][3],
+                f["q_dtype"], f["k_dtype"], f["map_tq"], f["page"]),
         ),
         Rule(
             "positions",

@@ -17,10 +17,26 @@ over this list (scalar-prefetched tile ids select the DMA'd kv tile),
 so cost is proportional to live cache content instead of
 O(n_refresh x total_len) dense work.
 
-Grid: (B, H, n_q_tiles, t_max) with the sparse key axis innermost;
-(m, l, acc) online-softmax scratch persists across it.  Ragged per-tile
-counts are handled with ``pl.when(it < count)``; fully-masked query
-rows (block-map padding, all-invalid caches) produce zeros.
+Grid: the sparse key axis is innermost; (m, l, acc) online-softmax
+scratch persists across it.  Ragged per-tile counts are handled with
+``pl.when(it < count)``; fully-masked query rows (block-map padding,
+all-invalid caches) produce zeros.
+
+* Paged kernel (``flash_refresh_paged_pallas``, single precision):
+  grid (B, Hkv, n_q_tiles, t_max).  One step takes all g = H // Hkv
+  query heads of one kv head: a (1, g, tq, D) q block of the
+  (B, H, Sq, D) transpose, used as one (g*tq, D) MXU operand against
+  the page DMA'd once for the group, and a (1, g, tq, D) output block;
+  the scratch has g*tq rows.  The mask is computed once per step as
+  (tq, tk) and shared by the g heads.  The ``group-vmem`` contract rule
+  (``paged_group_fits_vmem``) routes a group too large for VMEM to the
+  oracle.
+* Unpaged kernel (``flash_refresh_pallas``) and the paged int8 twin
+  (``_refresh_paged_quant_kernel``): grid (B, H, n_q_tiles, t_max), one
+  query head per step (kv head h // g).
+
+Every kernel here casts its operands to f32 in the kernel; the
+statistics and the accumulator are f32.
 """
 from __future__ import annotations
 
@@ -348,14 +364,20 @@ def _refresh_paged_kernel(
     o_ref, m_ref, l_ref, acc_ref,
     *, tk: int, t_max: int, scale: float, causal: bool, window: int | None,
 ):
-    """Same online-softmax body as ``_refresh_kernel``; the kv tile is
-    DMA'd from a shared batchless slab instead of a per-stream cache —
-    ``pt_ref`` is consumed by the BlockSpec index maps (visit list gives
-    a *logical* tile id, the page table turns it into a physical page).
-    The in-kernel mask stays logical: ``kp`` is the logical slot."""
+    """One grid step: all ``g`` query heads of one kv head against one
+    slab page.
+
+    ``q_ref`` is the (1, g, tq, D) block of those heads, taken as one
+    (g*tq, D) MXU operand, so the page DMA'd for this step is read once
+    for the whole group.  Operands are cast to f32 in the kernel, as in
+    ``_refresh_kernel``.  ``pt_ref`` is consumed by the BlockSpec index
+    maps (visit list gives a *logical* tile id, the page table turns it
+    into a physical page); the in-kernel mask stays logical, computed
+    once as (tq, tk) and shared by the g heads."""
     del pt_ref  # only used in the index maps
     iq = pl.program_id(2)
     it = pl.program_id(3)
+    _, g, tq, d = q_ref.shape
 
     @pl.when(it == 0)
     def _init():
@@ -366,35 +388,39 @@ def _refresh_paged_kernel(
     @pl.when(it < cnt_ref[iq])
     def _compute():
         kid = ids_ref[iq, it]
-        q = q_ref[0, 0].astype(jnp.float32) * scale     # (Tq, D)
+        q = q_ref[0].reshape(g * tq, d).astype(jnp.float32) * scale
         k = k_ref[0].astype(jnp.float32)                # (Tk, D) slab page
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        qp = qpos_ref[0]
+        ).reshape(g, tq, tk)
+        qp = qpos_ref[0]                                # (Tq, 1)
         kp = kid * tk + jax.lax.iota(jnp.int32, tk)[None, :]
-        mask = kvm_ref[0, 0] != 0
+        mask = kvm_ref[0, 0] != 0                       # (1, Tk)
         if causal:
             mask &= kp <= qp
         if window is not None:
             mask &= kp > qp - window
         logits = jnp.where(mask, logits, NEG_INF)
 
-        m_prev = m_ref[...]
+        m_prev = m_ref[...]                             # (g, Tq, 1)
         m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+        # multiply by the mask, not just NEG_INF-fill: for an all-masked
+        # tile m_new stays NEG_INF and exp(logits - m_new) would be 1.
         p = jnp.where(mask, jnp.exp(logits - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
         v = v_ref[0].astype(jnp.float32)
         pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+            p.reshape(g * tq, tk), v,
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        ).reshape(g, tq, d)
         acc_ref[...] = acc_ref[...] * corr + pv
         m_ref[...] = m_new
 
     @pl.when(it == t_max - 1)
     def _finish():
-        o_ref[0, 0] = (
+        # fully-masked rows have l == 0 and output exact zeros
+        o_ref[0] = (
             acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         ).astype(o_ref.dtype)
 
@@ -406,7 +432,8 @@ def _refresh_paged_quant_kernel(
     *, tk: int, t_max: int, scale: float, causal: bool, window: int | None,
     n_hot: int, n_cold: int, g: int,
 ):
-    """Two-precision twin of ``_refresh_paged_kernel``.
+    """Two-precision twin of ``_refresh_paged_kernel``, on the per-head
+    grid (one query head per step).
 
     The page table carries the precision bit: entry < n_hot is a hot
     (float) page, entry >= n_hot is cold page ``entry - n_hot`` in the
@@ -473,6 +500,33 @@ def _refresh_paged_quant_kernel(
         ).astype(o_ref.dtype)
 
 
+# The TPU compiler's default scoped VMEM limit (v5e): what one grid
+# step's blocks, scratch and temporaries must fit in.
+SCOPED_VMEM_BYTES = 16 * 2**20
+
+
+def paged_group_fits_vmem(g: int, d: int, q_dtype, kv_dtype,
+                          tq: int = 128, tk: int = 128) -> bool:
+    """Whether one step of ``_refresh_paged_kernel`` fits the scoped VMEM.
+
+    The step's working set, as ``flash_refresh_paged_pallas`` lays it
+    out: the (g*tq)-row q and output blocks, double-buffered; two
+    double-buffered kv pages; the lane-padded f32 running max and norm
+    and the f32 accumulator; the f32 copy of q and three (g*tq, tk) f32
+    tiles (logits, probabilities, their masked copy).  Checked against
+    compiles for a described v5e: at D 128 it admits bf16 g <= 28 (29
+    fits, 30 runs out) and f32 g <= 22 (24 fits, 25 runs out); at D 256
+    bf16 g <= 19 (24 fits, 28 runs out).  It errs on the side of the
+    oracle.
+    """
+    rows = g * tq
+    qo = 2 * jnp.dtype(q_dtype).itemsize
+    blocks = 2 * rows * d * qo + 4 * tk * d * jnp.dtype(kv_dtype).itemsize
+    scratch = rows * (2 * 128 * 4 + d * 4)
+    temporaries = rows * (d * 4 + 3 * tk * 4)
+    return blocks + scratch + temporaries <= SCOPED_VMEM_BYTES
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("page", "causal", "window", "tq", "tk", "interpret"),
@@ -515,7 +569,10 @@ def flash_refresh_paged_pallas(
         stays bitwise identical.
 
     Requires tk == page so one visit-list entry is one slab page (the
-    "page-tile" eligibility rule).  Returns (B, Sq, H, D).
+    "page-tile" eligibility rule).  Without ``cold`` one grid step takes
+    the g = H // Hkv query heads of a kv head together (module
+    docstring, "Grid").
+    Returns (B, Sq, H, D).
     """
     B, Sq, H, D = q.shape
     P_phys, Hkv, _ = k.shape
@@ -530,13 +587,13 @@ def flash_refresh_paged_pallas(
     assert tile_ids.shape[0] == n_q_tiles, (tile_ids.shape, n_q_tiles)
     scale = D ** -0.5
 
-    qt = q.transpose(0, 2, 1, 3)                      # (B, H, Sq, D)
     kt = k.transpose(1, 0, 2)                         # (Hkv, P_phys, D)
     vt = v.transpose(1, 0, 2)
     qp2 = q_pos.astype(jnp.int32).reshape(n_q_tiles, tq, 1)
     kvm = kv_valid.astype(jnp.int32).reshape(B, n_pages, 1, tk)
 
     if cold is not None:
+        qt = q.transpose(0, 2, 1, 3)                  # (B, H, Sq, D)
         k8, v8, k_scale, v_scale = cold
         n_hot = P_phys // page
         Pc_phys = k8.shape[0]
@@ -599,16 +656,17 @@ def flash_refresh_paged_pallas(
           qt, qp2, kt, k8t, vt, v8t, kvm)
         return out.transpose(0, 2, 1, 3)
 
+    qt = q.transpose(0, 2, 1, 3)                      # (B, H, Sq, D)
     kernel = functools.partial(
         _refresh_paged_kernel, tk=tk, t_max=t_max, scale=scale,
         causal=causal, window=window,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, H, n_q_tiles, t_max),
+        grid=(B, Hkv, n_q_tiles, t_max),
         in_specs=[
             pl.BlockSpec(
-                (1, 1, tq, D), lambda b, h, iq, it, ids, cnt, pt: (b, h, iq, 0)
+                (1, g, tq, D), lambda b, h, iq, it, ids, cnt, pt: (b, h, iq, 0)
             ),
             pl.BlockSpec(
                 (1, tq, 1), lambda b, h, iq, it, ids, cnt, pt: (iq, 0, 0)
@@ -616,11 +674,11 @@ def flash_refresh_paged_pallas(
             # visit list -> page table -> physical kv tile
             pl.BlockSpec(
                 (1, tk, D),
-                lambda b, h, iq, it, ids, cnt, pt: (h // g, pt[b, ids[iq, it]], 0),
+                lambda b, h, iq, it, ids, cnt, pt: (h, pt[b, ids[iq, it]], 0),
             ),
             pl.BlockSpec(
                 (1, tk, D),
-                lambda b, h, iq, it, ids, cnt, pt: (h // g, pt[b, ids[iq, it]], 0),
+                lambda b, h, iq, it, ids, cnt, pt: (h, pt[b, ids[iq, it]], 0),
             ),
             # validity stays logical (per stream, not per slab row)
             pl.BlockSpec(
@@ -629,12 +687,12 @@ def flash_refresh_paged_pallas(
             ),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, tq, D), lambda b, h, iq, it, ids, cnt, pt: (b, h, iq, 0)
+            (1, g, tq, D), lambda b, h, iq, it, ids, cnt, pt: (b, h, iq, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((tq, 1), jnp.float32),
-            pltpu.VMEM((tq, 1), jnp.float32),
-            pltpu.VMEM((tq, D), jnp.float32),
+            pltpu.VMEM((g, tq, 1), jnp.float32),   # running max  m
+            pltpu.VMEM((g, tq, 1), jnp.float32),   # running norm l
+            pltpu.VMEM((g, tq, D), jnp.float32),   # accumulator
         ],
     )
     out = pl.pallas_call(

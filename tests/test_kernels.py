@@ -12,7 +12,7 @@ from repro.kernels.flash_packed import (
 )
 from repro.kernels.flash_prefill import flash_prefill_pallas
 from repro.kernels.flash_refresh import (
-    build_block_map, dense_block_map, flash_refresh_pallas,
+    build_block_map, dense_block_map, flash_refresh_pallas, span_block_map,
 )
 from repro.kernels.mv_sad import mv_sad_pallas
 from repro.kernels.rope_shift import rope_shift_pallas
@@ -617,14 +617,37 @@ def test_paged_gather_matches_manual_indexing():
             np.testing.assert_array_equal(g[b, s], slab[phys])
 
 
-@pytest.mark.parametrize("pattern", sorted(SCATTER_PATTERNS))
-def test_flash_refresh_paged_matches_ref(pattern):
-    q_pos = SCATTER_PATTERNS[pattern]
-    slab_k, slab_v, pt, kvv = _paged_case(2, 2)
-    ks = jax.random.split(jax.random.PRNGKey(3), 1)
-    q = jax.random.normal(ks[0], (2, len(q_pos), 4, 32))
+def _paged_refresh_cases():
+    """(pattern, h, hkv, dtype) cases of the grouped paged kernel: query
+    groups g = H // Hkv of 1, 2, 5 (InternVL3-14B's 40/8) and 6 (12/2),
+    every scatter pattern plus a decode step, f32 and bf16.  The 4/2 f32
+    cases keep their original ids."""
+    patterns = sorted(SCATTER_PATTERNS) + ["decode"]
+    cases = [pytest.param(p, 4, 2, jnp.float32, id=p) for p in patterns]
+    for h, hkv in [(4, 4), (10, 2), (12, 2)]:
+        cases += [pytest.param(p, h, hkv, jnp.float32, id=f"{p}-h{h}kv{hkv}")
+                  for p in patterns]
+    for h, hkv in [(4, 4), (4, 2), (10, 2), (12, 2)]:
+        cases += [pytest.param(p, h, hkv, jnp.bfloat16,
+                               id=f"{p}-h{h}kv{hkv}-bf16")
+                  for p in ("anchors_tail", "decode")]
+    return cases
+
+
+@pytest.mark.parametrize("pattern,h,hkv,dtype", _paged_refresh_cases())
+def test_flash_refresh_paged_matches_ref(pattern, h, hkv, dtype):
+    if pattern == "decode":
+        # one query at a static position, mapped as serving decodes
+        q_pos = np.asarray([200], np.int32)
+        bm = span_block_map(200, 1, 256)
+    else:
+        q_pos = SCATTER_PATTERNS[pattern]
+        bm = build_block_map(q_pos, 256, tq=128, tk=128, causal=True)
+    slab_k, slab_v, pt, kvv = _paged_case(2, 2, hkv=hkv)
+    slab_k, slab_v = slab_k.astype(dtype), slab_v.astype(dtype)
+    q = jax.random.normal(jax.random.PRNGKey(3), (2, len(q_pos), h, 32))
+    q = q.astype(dtype)
     qp = jnp.broadcast_to(jnp.asarray(q_pos)[None], (2, len(q_pos)))
-    bm = build_block_map(q_pos, 256, tq=128, tk=128, causal=True)
     before = _guard_counts("flash_refresh_paged").get("kernel", 0)
     with ops.kernel_mode("interpret"):
         o_k = ops.flash_refresh_paged(
@@ -632,7 +655,10 @@ def test_flash_refresh_paged_matches_ref(pattern):
     assert _guard_counts("flash_refresh_paged").get("kernel", 0) == before + 1
     o_r = ref.flash_refresh_paged_ref(
         q, slab_k, slab_v, qp, kvv, pt, causal=True)
-    np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r), atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(o_k, np.float32), np.asarray(o_r, np.float32),
+        atol=3e-2 if dtype == jnp.bfloat16 else 1e-5,
+    )
 
 
 def test_flash_refresh_paged_oracle_bitwise_vs_dense_gather():
@@ -673,6 +699,29 @@ def test_flash_refresh_paged_page_tile_fallback():
         np.asarray(out),
         np.asarray(ref.flash_refresh_paged_ref(
             q, slab_k, slab_v, qp, kvv, pt, page=256, causal=True)),
+        atol=1e-6,
+    )
+
+
+def test_flash_refresh_paged_group_vmem_fallback():
+    """48 query heads on one kv head need a (6144, D) grouped step, more
+    than the scoped VMEM holds: the group-vmem eligibility rule routes
+    to the oracle, counted."""
+    q_pos = SCATTER_PATTERNS["anchors_tail"]
+    slab_k, slab_v, pt, kvv = _paged_case(1, 2, hkv=1, seed=31)
+    q = jax.random.normal(jax.random.PRNGKey(37), (1, len(q_pos), 48, 32))
+    qp = jnp.asarray(q_pos)[None]
+    bm = build_block_map(q_pos, 256, tq=128, tk=128, causal=True)
+    before = _guard_counts("flash_refresh_paged").get("guard:group-vmem", 0)
+    with ops.kernel_mode("interpret"):
+        out = ops.flash_refresh_paged(
+            q, slab_k, slab_v, qp, kvv, pt, block_map=bm, causal=True)
+    counts = _guard_counts("flash_refresh_paged")
+    assert counts.get("guard:group-vmem", 0) == before + 1
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(ref.flash_refresh_paged_ref(
+            q, slab_k, slab_v, qp, kvv, pt, causal=True)),
         atol=1e-6,
     )
 

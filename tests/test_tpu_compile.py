@@ -63,13 +63,14 @@ def _cold():
             ((COLD // PAGE, HKV), F32), ((COLD // PAGE, HKV), F32)]
 
 
-def _refresh_operands(paged: bool):
-    kv = (PHYS, HKV, D) if paged else (2, PAGES * PAGE, HKV, D)
-    ops = [((2, NQT * 128, H, D), BF16), (kv, BF16), (kv, BF16),
-           ((NQT * 128,), I32), ((2, PAGES * PAGE), jnp.bool_)]
+def _refresh_operands(paged: bool, nqt: int = NQT, h: int = H,
+                      hkv: int = HKV):
+    kv = (PHYS, hkv, D) if paged else (2, PAGES * PAGE, hkv, D)
+    ops = [((2, nqt * 128, h, D), BF16), (kv, BF16), (kv, BF16),
+           ((nqt * 128,), I32), ((2, PAGES * PAGE), jnp.bool_)]
     if paged:
         ops.append(((2, PAGES), I32))
-    return ops + [((NQT, TMAX), I32), ((NQT,), I32)]
+    return ops + [((nqt, TMAX), I32), ((nqt,), I32)]
 
 
 def _refresh_paged_cold(*a):
@@ -108,6 +109,12 @@ CASES = {
     "flash_refresh": (flash_refresh_pallas, _refresh_operands(False)),
     "flash_refresh_paged": (flash_refresh_paged_pallas,
                             _refresh_operands(True)),
+    # the decode step: one query tile against the whole window
+    "flash_refresh_paged-decode": (flash_refresh_paged_pallas,
+                                   _refresh_operands(True, nqt=1)),
+    # InternVL3-2B's LM (Qwen2.5-1.5B): 12 query heads on 2 kv heads
+    "flash_refresh_paged-g6": (flash_refresh_paged_pallas,
+                               _refresh_operands(True, h=12, hkv=2)),
     "flash_refresh_paged-int8": (_refresh_paged_cold,
                                  _refresh_operands(True) + _cold()),
     "flash_packed-internvit": (flash_packed_pallas, _PACKED),
