@@ -84,10 +84,13 @@ def window_work(w: Dict[str, Any], lay: Dict[str, Any],
     included) and ``kept`` (patches the vision tower needs per frame it
     encodes: whole I-frames, the kept groups' patches of P-frames).
     ``lay``: window geometry (``total``, ``refresh``: the positions an
-    incremental window recomputes).  Only positions that hold a token
-    count: padding slots are work the static shapes add, not work the
-    window needs.  Returns ``vit``, ``lm``, ``head``, ``decode``, and
-    the two attention kernels' FLOPs and bytes.
+    incremental window recomputes) and the LM's ``block``, which counts
+    the LM's part (``bench/lm/<block>.py``, ``work``).  Only positions
+    that hold a token count: padding slots are work the static shapes
+    add, not work the window needs.  Returns ``vit``, the block's
+    ``lm``, ``head``, ``decode`` and its attention kernel's FLOPs and
+    bytes (and any further counts of the block's), and the packed ViT
+    attention kernel's FLOPs and bytes.
     """
     valid = np.asarray(w["valid"], bool)
     kept = np.asarray(w["kept"], np.float64)
@@ -103,21 +106,12 @@ def window_work(w: Dict[str, Any], lay: Dict[str, Any],
     vit = v["n_layers"] * (n_patch * per_tok + 4.0 * float((kept ** 2).sum()) * d)
     vit += n_patch * 2 * (v["patch"] ** 2) * d
     vit += n_patch / g2 * 2 * (g2 * d) * lm["d_model"]
-    L, H, K, dh = lm["n_layers"], lm["n_heads"], lm["n_kv"], lm["d_head"]
-    lm_f = L * (len(q) * layer_flops_per_token(lm) + attn_flops(lm, pairs))
-    head = 2.0 * lm["d_model"] * lm["vocab"]
-    # the decode step attends every valid position and itself
-    decode = L * (layer_flops_per_token(lm) + attn_flops(lm, n_valid + 1)) + head
-    # paged flash attention (prefill or refresh, then the decode step):
-    # Q in and O out per valid query, K and V in once per valid key
-    ra_f = L * (attn_flops(lm, pairs) + attn_flops(lm, n_valid + 1))
-    ra_b = L * 2.0 * (2 * (len(q) + 1) * H * dh + 2 * 2 * (n_valid + 1) * K * dh)
+    lm_work = lay["block"].work(q, pairs, n_valid, w, lm)
     # packed ViT attention: P-frames only (I-frames take the dense path)
     pk = kept[kept < lay["n_patches"]]
     pa_f = v["n_layers"] * 4.0 * float((pk ** 2).sum()) * d
     pa_b = v["n_layers"] * 2.0 * 4 * float(pk.sum()) * d
-    return {"vit": vit, "lm": lm_f, "head": head, "decode": decode,
-            "refresh_attn_flops": ra_f, "refresh_attn_bytes": ra_b,
+    return {"vit": vit, **lm_work,
             "packed_attn_flops": pa_f, "packed_attn_bytes": pa_b}
 
 

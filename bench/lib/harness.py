@@ -3,8 +3,10 @@
 Everything a cell needs is found by name: the workload entry in
 ``BENCHMARK.json``; its configuration ``bench/configs/<config>.json``;
 its traffic ``bench/traffic/<traffic>.json``; its load
-``bench/cells/<workload>.json`` (streams in flight); and one reader per
-per-layer metric, ``bench/metrics/<metric>.py``.
+``bench/cells/<workload>.json`` (streams in flight); the language
+model's block that the configuration names, ``bench/lm/<block>.py``
+(``bench/lib/blocks.py``); and one reader per per-layer metric,
+``bench/metrics/<metric>.py``.
 
 The run drives the program's ``Scheduler`` itself: ``submit`` for each
 camera segment or clip, ``step`` in a loop, a new segment submitted as
@@ -14,17 +16,18 @@ from __future__ import annotations
 
 import dataclasses
 import gc
-import importlib.util
 import json
 import math
 import shutil
 import sys
 import time
 from pathlib import Path
-from typing import Any, Dict, List, Optional
+from types import ModuleType
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from . import blocks
 from . import peaks as peakmod
 from . import stats as statmod
 from . import trace as tracemod
@@ -34,7 +37,6 @@ from .reference import QUERY_IDS, NO, YES, Layout, Reference
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = ROOT / "bench"
-KERNELS = ("flash_refresh_paged", "flash_packed")
 # warm-up runs at least one full period of the traffic and this many
 # rounds of the fleet more, and stops once a whole period has passed
 # with no new executable (the schedule is periodic: every group the
@@ -61,6 +63,7 @@ class Cell:
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
     bench_dir: Path
+    block: ModuleType
 
     @property
     def streams(self) -> int:
@@ -91,41 +94,41 @@ def load_cell(name: str, benchmark: Optional[Dict[str, Any]] = None,
         name=name, entry=entry, conf=conf, mix=mix, load=load,
         end_to_end=[m for m in benchmark["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in benchmark["per_layer"] if _applies(m, name)],
-        bench_dir=bench_dir)
+        bench_dir=bench_dir, block=blocks.load(conf["lm"], bench_dir))
 
 
-def program_cfg(conf: Dict[str, Any]):
-    """The program's (ModelCfg, ViTCfg) for a configuration file: a
-    registry entry by name, checked against the file's numbers, or the
-    file's inline numbers."""
-    from repro.configs import ModelCfg, ViTCfg, get_config
+def kernels(block: ModuleType) -> Tuple[str, ...]:
+    """The kernels whose device time the trace sums: the LM block's and
+    the vision tower's packed attention."""
+    return tuple(block.KERNELS) + ("flash_packed",)
 
-    lm, v = dict(conf["lm"]), dict(conf["vit"])
-    vit = ViTCfg(**{k: v[k] for k in ("n_layers", "d_model", "n_heads",
+
+def vit_cfg(conf: Dict[str, Any]):
+    from repro.configs import ViTCfg
+
+    v = conf["vit"]
+    return ViTCfg(**{k: v[k] for k in ("n_layers", "d_model", "n_heads",
                                        "d_ff", "patch", "image", "group")})
-    fields = {k: lm[k] for k in ("n_layers", "d_model", "n_heads", "n_kv",
-                                 "d_head", "d_ff", "vocab", "qkv_bias",
-                                 "rope_theta", "norm_eps", "tied_embeddings",
-                                 "dtype", "img_tokens")}
-    if conf.get("arch"):
-        cfg = get_config(conf["arch"])
-        got = {k: getattr(cfg, k) for k in fields}
-        if got != fields or cfg.vit != vit or cfg.family != "vlm":
-            raise ValueError(f"registry entry {conf['arch']} differs from "
-                             f"its configuration file: {got} vs {fields}")
-        return cfg, vit
-    return ModelCfg(name=conf["name"], family="vlm", vit=vit,
-                    source=conf["source"], **fields), vit
 
 
-def geometry(conf: Dict[str, Any], codec: Dict[str, Any]) -> Dict[str, Any]:
-    """Static window geometry for the work counts (``flops.window_work``)."""
+def program_cfg(conf: Dict[str, Any], block: ModuleType):
+    """The program's (ModelCfg, ViTCfg) for a configuration file; the
+    ModelCfg is the LM block's (``model_cfg``)."""
+    vit = vit_cfg(conf)
+    return block.model_cfg(conf, vit), vit
+
+
+def geometry(conf: Dict[str, Any], codec: Dict[str, Any],
+             block: ModuleType) -> Dict[str, Any]:
+    """Static window geometry and the LM's block, for the work counts
+    (``flops.window_work``)."""
     lay = Layout(codec, conf["vit"])
     v = conf["vit"]
     return {"total": lay.total, "refresh": lay.refresh,
             "frames_fresh": lay.window, "frames_inc": lay.stride,
             "n_patches": (v["image"] // v["patch"]) ** 2,
-            "layout": lay}
+            "layout": lay,
+            "block": block}
 
 
 def attach_work(windows, segments, pool, cell: Cell, schedule) -> None:
@@ -249,17 +252,16 @@ class Driver:
             if isinstance(ev, self._win):
                 s = ev.result.stats
                 yn = s.logits_yes_no
-                self.windows.append({
+                rec = {f.name: getattr(s, f.name)
+                       for f in dataclasses.fields(s)}
+                rec.update({
                     "t": t1, "step": len(self.steps) - 1, "sid": ev.sid,
                     "window": ev.result.window,
-                    "tokens_vis": s.tokens_vis,
-                    "tokens_valid": s.tokens_valid,
-                    "tokens_refreshed": s.tokens_refreshed,
-                    "vit_patches": s.vit_patches,
-                    "yes": yn[0], "no": yn[1], "answer": s.answer,
+                    "yes": yn[0], "no": yn[1],
                     "finite": bool(math.isfinite(yn[0])
                                    and math.isfinite(yn[1])),
                 })
+                self.windows.append(rec)
                 self.open[ev.sid] = self.open.get(ev.sid, 0) + 1
                 n += 1
             elif isinstance(ev, self._done):
@@ -288,12 +290,8 @@ def _device(require_tpu: bool, chips: int):
 
 
 def _read_metric(bench_dir: Path, name: str):
-    path = bench_dir / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return blocks.load_module(bench_dir / "metrics" / f"{name}.py",
+                              "bench_metric_").read
 
 
 @dataclasses.dataclass
@@ -332,8 +330,11 @@ def per_layer_metrics(cell: Cell, view: RunView) -> Dict[str, Any]:
 def run(cell: Cell, seed: int, seconds: float, trace: bool,
         t_start: float, require_tpu: bool = True,
         scratch: Optional[Path] = None, log=None,
-        compile_cache: bool = True, control: bool = False) -> Dict[str, Any]:
-    """One run of ``cell``; returns the result line (a dict)."""
+        compile_cache: bool = True, control: bool = False,
+        records: Optional[List[Dict[str, Any]]] = None) -> Dict[str, Any]:
+    """One run of ``cell``; returns the result line (a dict).  Every
+    answered window's record, warm-up and drain included, is appended
+    to ``records`` where it is given."""
     import jax
 
     def say(msg):
@@ -352,13 +353,13 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         serve.enable_compile_cache()
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     conf, mix, F = cell.conf, cell.mix, cell.streams
-    cfg, vcfg = program_cfg(conf)
+    cfg, vcfg = program_cfg(conf, cell.block)
     weightmod.check_layout(
-        weightmod.shapes(conf["lm"], conf["vit"]),
+        weightmod.shapes(conf["lm"], conf["vit"], cell.block),
         jax.eval_shape(lambda: serve.init_weights(cfg, vcfg, 0)))
     say(f"[{time.perf_counter() - t_start:.1f} s] device and config")
     params, vparams = jax.block_until_ready(
-        weightmod.make_weights(conf["lm"], conf["vit"], seed))
+        weightmod.make_weights(conf["lm"], conf["vit"], seed, cell.block))
     say(f"[{time.perf_counter() - t_start:.1f} s] weights")
     pool = trafficmod.build_pool(mix, F)
     say(f"[{time.perf_counter() - t_start:.1f} s] traffic pool")
@@ -441,11 +442,13 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     if trace:
         attach_work(work, drv.segments, pool, cell, schedule)
         tr = tracemod.load(tracemod.find(str(tdir)))
-        summ = tracemod.summarize(tr, KERNELS)
+        summ = tracemod.summarize(tr, kernels(cell.block))
         say(f"trace: busy {summ.busy_s:.3f} s of {summ.window_s:.3f} s, "
             f"kernels {summ.kernel_s}")
         shutil.rmtree(tdir, ignore_errors=True)
     windows_all = drv.windows
+    if records is not None:
+        records.extend(windows_all)
     submits = [s for s in drv.submits if t0 < s[0] <= t1]
     compiles = clog.count(t0, t1)
     del drv, sched, pipe
@@ -457,7 +460,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
                             finished, by_sid, say, control=control)
     say(f"reference {time.perf_counter() - t_r:.1f} s")
 
-    geo = geometry(conf, mix.codec)
+    geo = geometry(conf, mix.codec, cell.block)
     out: Dict[str, Any] = {"correct": bool(ok and failed == 0),
                            "attempted": attempted, "failed": failed}
     metrics: Dict[str, Any] = {}
@@ -562,7 +565,8 @@ def check(cell: Cell, seed: int, params, vparams, pool, schedule,
     ``control`` also serves the sample with the fp8 control in the
     program's place and reads it the same way."""
     conf = cell.conf
-    args = (conf["lm"], conf["vit"], cell.mix.codec, params, vparams)
+    args = (conf["lm"], conf["vit"], cell.mix.codec, params, vparams,
+            cell.block)
     ref = Reference(*args)
     low = Reference(*args, precision="bf16")
     ctl = Reference(*args, precision="fp8") if control else None

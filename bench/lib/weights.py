@@ -4,14 +4,16 @@ The weights are the benchmark's input data: the program is handed them,
 and the plain reference (``reference.py``) reads the same arrays.  So
 the reference never reads anything the program made.
 
-The tree below is the program's parameter layout (its checkpoint
-format): names, shapes and storage dtypes.  ``check_layout`` compares
+The tree is the program's parameter layout (its checkpoint format):
+names, shapes and storage dtypes, the LM's from its block
+(``bench/lm/<block>.py``, ``layout``).  ``check_layout`` compares
 it with the layout the program itself builds, so a change of format
 fails loudly at set-up instead of serving a model nobody checked.
 """
 from __future__ import annotations
 
 import math
+from types import ModuleType
 from typing import Any, Dict
 
 import jax
@@ -34,38 +36,13 @@ class Leaf:
         self.scale = scale
 
 
-def _dense(shape, fan_in=None, scale=None):
+def dense(shape, fan_in=None, scale=None):
+    """A bfloat16 matrix drawn with standard deviation ``scale``, by
+    default ``fan_in ** -0.5`` (``fan_in`` defaults to the second-to-last
+    axis)."""
     fan_in = fan_in if fan_in is not None else shape[-2]
     return Leaf(shape, BF16, "dense", scale if scale is not None
                 else fan_in ** -0.5)
-
-
-def lm_layout(lm: Dict[str, Any]) -> Dict[str, Any]:
-    d, H, K = lm["d_model"], lm["n_heads"], lm["n_kv"]
-    dh, f, R, V = lm["d_head"], lm["d_ff"], lm["n_layers"], lm["vocab"]
-    mixer = {
-        "wq": _dense((R, d, H * dh), d),
-        "wk": _dense((R, d, K * dh), d),
-        "wv": _dense((R, d, K * dh), d),
-        "wo": _dense((R, H * dh, d), H * dh),
-    }
-    if lm["qkv_bias"]:
-        mixer["bq"] = Leaf((R, H * dh), BF16, "bias", 0.02)
-        mixer["bk"] = Leaf((R, K * dh), BF16, "bias", 0.02)
-        mixer["bv"] = Leaf((R, K * dh), BF16, "bias", 0.02)
-    block = {
-        "ln1": {"scale": Leaf((R, d), F32, "norm")},
-        "ln2": {"scale": Leaf((R, d), F32, "norm")},
-        "mixer": mixer,
-        "ffn": {"wg": _dense((R, d, f), d), "wu": _dense((R, d, f), d),
-                "wd": _dense((R, f, d), f)},
-    }
-    tree = {"embed": _dense((V, d), scale=0.02),
-            "final_norm": {"scale": Leaf((d,), F32, "norm")}}
-    if not lm["tied_embeddings"]:
-        tree["lm_head"] = _dense((d, V), d)
-    tree["blocks"] = (block,)
-    return tree
 
 
 def vit_layout(v: Dict[str, Any], d_lm: int) -> Dict[str, Any]:
@@ -73,18 +50,18 @@ def vit_layout(v: Dict[str, Any], d_lm: int) -> Dict[str, Any]:
     p2, g2 = v["patch"] ** 2, v["group"] ** 2
     n_patches = (v["image"] // v["patch"]) ** 2
     return {
-        "patch_embed": _dense((p2, d), p2),
-        "pos_embed": _dense((n_patches, d), scale=0.02),
+        "patch_embed": dense((p2, d), p2),
+        "pos_embed": dense((n_patches, d), scale=0.02),
         "blocks": {
             "ln1": {"scale": Leaf((R, d), F32, "norm")},
-            "wq": _dense((R, d, d), d), "wk": _dense((R, d, d), d),
-            "wv": _dense((R, d, d), d), "wo": _dense((R, d, d), d),
+            "wq": dense((R, d, d), d), "wk": dense((R, d, d), d),
+            "wv": dense((R, d, d), d), "wo": dense((R, d, d), d),
             "ln2": {"scale": Leaf((R, d), F32, "norm")},
-            "ffn": {"wg": _dense((R, d, f), d), "wu": _dense((R, d, f), d),
-                    "wd": _dense((R, f, d), f)},
+            "ffn": {"wg": dense((R, d, f), d), "wu": dense((R, d, f), d),
+                    "wd": dense((R, f, d), f)},
         },
         "final_norm": {"scale": Leaf((d,), F32, "norm")},
-        "projector": _dense((g2 * d, d_lm), g2 * d),
+        "projector": dense((g2 * d, d_lm), g2 * d),
     }
 
 
@@ -124,10 +101,16 @@ def root_key(seed: int):
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-def make_weights(lm: Dict[str, Any], vit: Dict[str, Any], seed: int):
+def _layout(lm: Dict[str, Any], vit: Dict[str, Any], block: ModuleType):
+    return block.layout(lm), vit_layout(vit, lm["d_model"])
+
+
+def make_weights(lm: Dict[str, Any], vit: Dict[str, Any], seed: int,
+                 block: ModuleType):
     """(LM params, ViT params) for the config, drawn on the device in
-    one jitted call; every leaf in its storage dtype."""
-    layout = (lm_layout(lm), vit_layout(vit, lm["d_model"]))
+    one jitted call; every leaf in its storage dtype.  The LM's leaves
+    are those of its ``block`` (``bench/lm/<block>.py``)."""
+    layout = _layout(lm, vit, block)
     leaves, treedef = jax.tree_util.tree_flatten(layout, is_leaf=_is_leaf)
 
     def build(key):
@@ -138,9 +121,9 @@ def make_weights(lm: Dict[str, Any], vit: Dict[str, Any], seed: int):
     return jax.jit(build)(root_key(seed))
 
 
-def shapes(lm: Dict[str, Any], vit: Dict[str, Any]):
+def shapes(lm: Dict[str, Any], vit: Dict[str, Any], block: ModuleType):
     """The weight tree as ShapeDtypeStructs (no allocation)."""
-    layout = (lm_layout(lm), vit_layout(vit, lm["d_model"]))
+    layout = _layout(lm, vit, block)
     return jax.tree_util.tree_map(
         lambda lf: jax.ShapeDtypeStruct(lf.shape, lf.dtype), layout,
         is_leaf=_is_leaf)

@@ -27,14 +27,15 @@ ROOT = Path(__file__).resolve().parent.parent
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 
-def report(path: str) -> dict:
-    """What the kept trace says about the serving loop's spans."""
-    from bench.lib import harness, spans, stages
+def report(path: str, kernels) -> dict:
+    """What the kept trace says about the serving loop's spans; the
+    device time of ``kernels`` summed."""
+    from bench.lib import spans, stages
     from bench.lib import trace as tracemod
 
     tr = tracemod.load(path)
     sp = spans.load(path)
-    summ = tracemod.summarize(tr, harness.KERNELS)
+    summ = tracemod.summarize(tr, kernels)
     split = stages.split(summ.op_s)
     fetches = spans.fetches(sp)
     by_fetch = {}
@@ -92,7 +93,8 @@ def main() -> int:
     finally:
         tracemod.load = load
     print(json.dumps(res), flush=True)
-    print(json.dumps(report(str(kept))), flush=True)
+    print(json.dumps(report(str(kept), harness.kernels(cell.block))),
+          flush=True)
     return 0
 
 

@@ -129,9 +129,9 @@ def main() -> int:
     tmp = Path(tempfile.mkdtemp())
     cell = harness.load_cell(TINY, benchmark_with_tiny(), bench_dir(tmp))
     F = cell.streams
-    cfg, vcfg = harness.program_cfg(cell.conf)
+    cfg, vcfg = harness.program_cfg(cell.conf, cell.block)
     params, vparams = weights.make_weights(cell.conf["lm"], cell.conf["vit"],
-                                           SEED)
+                                           SEED, cell.block)
     pool = traffic.build_pool(cell.mix, F)
     pipe = ServingPipeline(cfg, vcfg, params, vparams, EngineCfg(
         mode="codecflow", codec=CodecCfg(**cell.mix.codec),
@@ -200,7 +200,7 @@ def main() -> int:
     summary = {"probe_bytes": probe.stat().st_size,
                "guarded_windows": len(drv.windows) - n0,
                "guarded_fetch_spans": fetched,
-               "report": report(str(probe))}
+               "report": report(str(probe), harness.kernels(cell.block))}
     print(json.dumps(summary), flush=True)
     shutil.rmtree(tmp, ignore_errors=True)
     return 0

@@ -45,8 +45,9 @@ def test_work_tables_match_the_programs_counts(cell):
                                ServingPipeline)
 
     conf, mix, F = cell.conf, cell.mix, cell.streams
-    cfg, v = harness.program_cfg(conf)
-    params, vparams = weights.make_weights(conf["lm"], conf["vit"], 3)
+    cfg, v = harness.program_cfg(conf, cell.block)
+    params, vparams = weights.make_weights(conf["lm"], conf["vit"], 3,
+                                           cell.block)
     pool = traffic.build_pool(mix, F)
     sch = traffic.Schedule(mix, F)
     pipe = ServingPipeline(cfg, v, params, vparams, EngineCfg(

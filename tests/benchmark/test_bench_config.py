@@ -6,7 +6,7 @@ import jax
 import pytest
 
 from bench_helpers import DATA, ROOT
-from bench.lib import harness, weights
+from bench.lib import blocks, harness, weights
 
 CONFIGS = ROOT / "bench" / "configs"
 
@@ -27,7 +27,8 @@ def _registry_form():
 def test_registry_config_matches_its_file():
     from repro.configs import get_config
 
-    cfg, v = harness.program_cfg(_registry_form())
+    conf = _registry_form()
+    cfg, v = harness.program_cfg(conf, blocks.load(conf["lm"]))
     assert cfg == get_config("internvl3-14b-1chip")
     assert v == cfg.vit and cfg.n_layers == 16 and cfg.d_model == 5120
 
@@ -36,11 +37,12 @@ def test_registry_config_that_drifts_is_refused():
     conf = _registry_form()
     conf["lm"]["n_layers"] = 48
     with pytest.raises(ValueError, match="differs"):
-        harness.program_cfg(conf)
+        harness.program_cfg(conf, blocks.load(conf["lm"]))
 
 
 def test_inline_config():
-    cfg, v = harness.program_cfg(_conf("internvl3-2b"))
+    conf = _conf("internvl3-2b")
+    cfg, v = harness.program_cfg(conf, blocks.load(conf["lm"]))
     assert (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv, cfg.d_head,
             cfg.d_ff, cfg.vocab) == (28, 1536, 12, 2, 128, 8960, 151674)
     assert cfg.qkv_bias and cfg.tied_embeddings and cfg.family == "vlm"
@@ -48,7 +50,8 @@ def test_inline_config():
 
 
 def test_14b_config_states_the_published_lm():
-    cfg, _ = harness.program_cfg(_conf("internvl3-14b-1chip"))
+    conf = _conf("internvl3-14b-1chip")
+    cfg, _ = harness.program_cfg(conf, blocks.load(conf["lm"]))
     assert (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv, cfg.d_ff,
             cfg.vocab) == (16, 5120, 40, 8, 13824, 151674)
     assert cfg.qkv_bias and not cfg.tied_embeddings
@@ -62,24 +65,28 @@ def test_weight_layout_is_the_programs(name, d):
     from repro.launch import serve
 
     conf = _conf(name, d)
-    cfg, v = harness.program_cfg(conf)
-    weights.check_layout(weights.shapes(conf["lm"], conf["vit"]),
+    block = blocks.load(conf["lm"])
+    cfg, v = harness.program_cfg(conf, block)
+    weights.check_layout(weights.shapes(conf["lm"], conf["vit"], block),
                          jax.eval_shape(lambda: serve.init_weights(cfg, v, 0)))
 
 
 def test_layout_mismatch_is_refused():
     conf = _conf("tiny", DATA / "configs")
-    mine = weights.shapes(conf["lm"], conf["vit"])
+    block = blocks.load(conf["lm"])
+    mine = weights.shapes(conf["lm"], conf["vit"], block)
     conf["lm"]["d_ff"] = 96
     with pytest.raises(ValueError, match="differs"):
-        weights.check_layout(mine, weights.shapes(conf["lm"], conf["vit"]))
+        weights.check_layout(mine, weights.shapes(conf["lm"], conf["vit"],
+                                                  block))
 
 
 def test_weights_are_drawn_from_the_seed():
     conf = _conf("tiny", DATA / "configs")
-    a = weights.make_weights(conf["lm"], conf["vit"], 2**33 + 1)
-    b = weights.make_weights(conf["lm"], conf["vit"], 2**33 + 1)
-    c = weights.make_weights(conf["lm"], conf["vit"], 1)
+    block = blocks.load(conf["lm"])
+    a = weights.make_weights(conf["lm"], conf["vit"], 2**33 + 1, block)
+    b = weights.make_weights(conf["lm"], conf["vit"], 2**33 + 1, block)
+    c = weights.make_weights(conf["lm"], conf["vit"], 1, block)
     la, lb, lc = (jax.tree_util.tree_leaves(t) for t in (a, b, c))
     assert all((x == y).all() for x, y in zip(la, lb))
     assert not all((x == y).all() for x, y in zip(la, lc))

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from bench_helpers import DATA, ROOT
-from bench.lib import flops, harness
+from bench.lib import blocks, flops, harness
 from repro.serving import flops as ledger
 
 CONFIGS = [DATA / "configs" / "tiny.json",
@@ -16,7 +16,7 @@ CONFIGS = [DATA / "configs" / "tiny.json",
 @pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
 def test_copy_matches_program_ledger(path):
     conf = json.loads(path.read_text())
-    cfg, v = harness.program_cfg(conf)
+    cfg, v = harness.program_cfg(conf, blocks.load(conf["lm"]))
     for n in (4, 64, 1024):
         assert flops.vit_flops(conf["vit"], n) == ledger.vit_flops(v, n)
     for n_q, n_kv in ((1, 100), (50, 300), (300, 300)):
@@ -29,7 +29,7 @@ def test_copy_matches_program_ledger(path):
 def _geo_2b():
     conf = json.loads((ROOT / "bench" / "configs" / "internvl3-2b.json").read_text())
     codec = json.loads((ROOT / "bench" / "traffic" / "cctv-sessions.json").read_text())["codec"]
-    return conf, harness.geometry(conf, codec)
+    return conf, harness.geometry(conf, codec, blocks.load(conf["lm"]))
 
 
 def test_fresh_window_with_every_slot_valid_is_the_ledger_less_padding():

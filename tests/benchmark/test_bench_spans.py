@@ -81,7 +81,8 @@ def test_gaps_are_named_by_serve_spans(tr, sp):
 
 
 def test_stage_times_add_up_to_busy(tr):
-    s = trace.summarize(tr, harness.KERNELS)
+    cell = harness.load_cell("ivl3-14b.cctv-sessions")
+    s = trace.summarize(tr, harness.kernels(cell.block))
     split = stages.split(s.op_s)
     assert sum(split.values()) == pytest.approx(s.busy_s, rel=0.01)
     assert not any(k.startswith("jit__lambda") for k in s.op_s)
@@ -91,10 +92,10 @@ def test_stage_times_add_up_to_busy(tr):
 
 def _view(tr, n_windows):
     cell = harness.load_cell("ivl3-14b.cctv-sessions")
-    geo = harness.geometry(cell.conf, cell.mix.codec)
+    geo = harness.geometry(cell.conf, cell.mix.codec, cell.block)
     win = [{"tokens_refreshed": geo["total"]}] * n_windows
     return harness.RunView(cell, geo, 0.0, 1.0, win, win, [], 0,
-                           trace.summarize(tr, harness.KERNELS),
+                           trace.summarize(tr, harness.kernels(cell.block)),
                            {"peak": 1, "limit": 2}, None, "TPU v5 lite")
 
 

@@ -24,9 +24,9 @@ def jitted(tmp_path_factory):
     tmp = tmp_path_factory.mktemp("stages")
     cell = harness.load_cell(TINY, benchmark_with_tiny(), bench_dir(tmp))
     F = cell.streams
-    cfg, vcfg = harness.program_cfg(cell.conf)
+    cfg, vcfg = harness.program_cfg(cell.conf, cell.block)
     params, vparams = weights.make_weights(cell.conf["lm"],
-                                           cell.conf["vit"], 3)
+                                           cell.conf["vit"], 3, cell.block)
     pipe = ServingPipeline(cfg, vcfg, params, vparams, EngineCfg(
         mode="codecflow", codec=CodecCfg(**cell.mix.codec),
         kv=KVCfg(pool_streams=F)))
@@ -100,7 +100,7 @@ def test_split_counts_every_operation_once():
 def test_stage_readers_find_nothing_without_a_trace(name):
     read = harness._read_metric(ROOT / "bench", name)
     cell = harness.load_cell("ivl3-14b.cctv-sessions")
-    geo = harness.geometry(cell.conf, cell.mix.codec)
+    geo = harness.geometry(cell.conf, cell.mix.codec, cell.block)
     win = [{"tokens_refreshed": geo["total"]}]
     view = harness.RunView(cell, geo, 0.0, 1.0, win, win, [], 0, None,
                            {"peak": 1, "limit": 2}, None, "cpu")
