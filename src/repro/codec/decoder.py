@@ -22,12 +22,17 @@ from .metadata import Bitstream, CodecMetadata, I_FRAME
 
 @functools.partial(jax.jit, static_argnames=("block",))
 def decode_stream(bitstream: Bitstream, block: int = 16) -> jnp.ndarray:
-    """Reconstruct all frames (exact inverse of ``encode_stream``)."""
+    """Reconstruct all frames (exact inverse of ``encode_stream``).
+
+    Motion compensation uses the bitstream's own ``radius``, the
+    encoder's: static shifts where it is set, a per-pixel gather where
+    it is None.  The frames are the same either way.
+    """
 
     def step(prev_recon, inp):
         ftype, idata, mv, resid = inp
         is_i = ftype == I_FRAME
-        pred = motion_compensate(prev_recon, mv, block)
+        pred = motion_compensate(prev_recon, mv, block, bitstream.radius)
         recon = jnp.where(is_i, idata, pred + resid)
         return recon, recon
 

@@ -7,7 +7,7 @@ byproduct of inter-frame prediction.
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -55,12 +55,24 @@ class Bitstream(NamedTuple):
         entropy model in ``encoder.estimate_bits``).
       mv: (T, Hb, Wb, 2) int32.
       residual_q: (T, H, W) float32 — quantized P-frame residuals.
+      radius: the encoder's search radius, which bounds every vector of
+        ``mv``; static (pytree metadata, not a leaf), so the decoder
+        builds its prediction from static shifts.  None where the
+        vectors are not known to be bounded: the decoder then gathers.
     """
 
     frame_types: jnp.ndarray
     iframe_data: jnp.ndarray
     mv: jnp.ndarray
     residual_q: jnp.ndarray
+    radius: Optional[int] = None
+
+
+jax.tree_util.register_pytree_node(
+    Bitstream,
+    lambda b: (tuple(b[:4]), b.radius),
+    lambda radius, leaves: Bitstream(*leaves, radius),
+)
 
 
 def gop_frame_types(n_frames: int, gop: int) -> jnp.ndarray:

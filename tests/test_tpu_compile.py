@@ -7,7 +7,9 @@ catches what interpret mode cannot — blocks the TPU tiling refuses,
 vector layouts Mosaic cannot build — at no chip cost.  Widths: the
 InternVL3-14B LM (40 query / 8 KV heads of 128) with its 2-stream paged
 slab, the InternViT-300M encoder (16 heads of 64), 448-px codec frames,
-and the mamba2-2.7b SSD mixer (80 heads of 64, d_state 128).
+and the mamba2-2.7b SSD mixer (80 heads of 64, d_state 128).  The codec's
+segment programs (``encode_stream``, ``decode_stream``) are compiled too,
+and must hold no gather.
 
 The topology is described inside a module fixture, never at import:
 only the worker that runs this file may load the TPU compiler library.
@@ -17,6 +19,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.codec import decode_stream, encode_stream
+from repro.codec.metadata import Bitstream
+from repro.configs.base import CodecCfg
+from repro.kernels import ops
 from repro.kernels.flash_packed import flash_packed_pallas
 from repro.kernels.flash_prefill import (
     flash_prefill_paged_pallas, flash_prefill_pallas,
@@ -134,3 +140,25 @@ def test_kernel_compiles_for_v5e(case, one_chip):
             for shape, dtype in operands]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("fn", ["encode_stream", "decode_stream"])
+def test_codec_compiles_for_v5e_without_gather(fn, one_chip):
+    """One served segment (92 frames of 448 px, radius 4) with the search
+    on the ``mv_sad`` kernel: the v5e program holds no gather, so motion
+    compensation stays static shifts of the padded reference."""
+    f = jax.ShapeDtypeStruct((92, 448, 448), F32, sharding=one_chip)
+    i = jax.ShapeDtypeStruct((92,), I32, sharding=one_chip)
+    mv = jax.ShapeDtypeStruct((92, 28, 28, 2), I32, sharding=one_chip)
+    jax.clear_caches()  # the trace cache does not key on the kernel mode
+    with ops.kernel_mode("pallas"):
+        if fn == "encode_stream":
+            lowered = encode_stream.lower(
+                f, CodecCfg(gop=4, block=16, search_radius=4))
+        else:
+            lowered = decode_stream.lower(Bitstream(i, f, mv, f, 4), 16)
+        hlo = lowered.compile().as_text()
+    jax.clear_caches()
+    assert "gather(" not in hlo
+    if fn == "encode_stream":
+        assert "tpu_custom_call" in hlo
